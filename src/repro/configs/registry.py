@@ -2,10 +2,13 @@
 configs from the assignment) + reduced smoke-test variants of the same family.
 
 ``get("--arch id")`` resolves CLI flags; ``reduced(cfg)`` derives the CPU smoke
-variant (same structure, small shapes).
+variant (same structure, small shapes); ``published(cfg, n)`` keeps every
+published width and cuts depth to ``n`` layers (one chip's share).
 """
 from __future__ import annotations
 
+import dataclasses
+import math
 from typing import Dict
 
 from . import (chameleon_34b, command_r_plus_104b, dbrx_132b, glm4_9b,
@@ -26,6 +29,25 @@ def get(name: str) -> ArchConfig:
     if name not in ARCHS:
         raise KeyError(f"unknown arch {name!r}; known: {sorted(ARCHS)}")
     return ARCHS[name]
+
+
+def published(cfg: ArchConfig, n_layers: int) -> ArchConfig:
+    """The published config cut to ``n_layers`` whole layers.
+
+    Depth is the only cut: every width (d_model, heads, KV heads, head_dim,
+    d_ff, vocab, experts) stays as published.  The depth must be a whole
+    number of the family's layer periods (attention period, MoE period)."""
+    period = math.lcm(max(cfg.attn_period, 1),
+                      cfg.moe.every_n if cfg.moe else 1)
+    if not 1 <= n_layers <= cfg.n_layers or n_layers % period:
+        raise ValueError(
+            f"{cfg.name}: cannot keep {n_layers} of {cfg.n_layers} layers "
+            f"(need a multiple of the {period}-layer period, at most "
+            f"{cfg.n_layers})")
+    if n_layers == cfg.n_layers:
+        return cfg
+    return dataclasses.replace(cfg, name=f"{cfg.name}-{n_layers}L",
+                               n_layers=n_layers)
 
 
 def reduced(cfg: ArchConfig) -> ArchConfig:

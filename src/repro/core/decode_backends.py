@@ -19,12 +19,18 @@ instead of an ad-hoc per-call-site import:
 
 * ``register_backend`` / ``get_backend`` — a string-keyed registry
   (``"numpy"``, ``"jax"``, ``"pallas"``, ``"pallas-interpret"``).
-* Capability probing — each backend reports :meth:`DecoderBackend.available`;
-  the ``pallas`` backend probes whether the kernels actually *compile* on
-  this host (``interpret=False``).  Interpret mode is never auto-picked: it
-  exists only as the explicitly named ``"pallas-interpret"`` fallback.
-* ``auto_pick`` — capability-based default: compiled Pallas on TPU, the jit
-  decoder when an accelerator is attached, the numpy host path otherwise.
+* Rules, not probes — each backend states where it runs
+  (:meth:`DecoderBackend.available`) and when ``auto`` may pick it
+  (:meth:`DecoderBackend.auto_eligible`) as plain checks of the platform,
+  with the reason in ``auto_why``.  Nothing is compiled to find out and no
+  compiler error is swallowed: a backend named on a host where its kernel
+  does not compile fails with the compiler's own error.
+* ``auto_pick`` — the highest-priority eligible backend: the jit decoder
+  when an accelerator is attached, the numpy host path otherwise.  The
+  compiled Pallas kernels are never auto-picked while
+  :data:`PALLAS_DECODE_LOWERS` is False (``tests/test_tpu_compile.py`` pins
+  Mosaic's refusal against a described v5e), and interpret mode runs only
+  when ``pallas-interpret`` is named.
 * :meth:`DecoderBackend.decode_table` — codec-aware entry point: a
   :class:`repro.core.codecs.base.CodeTable` names its kernel family and
   supplies the gather arrays; the backend routes to the right loop.
@@ -56,12 +62,12 @@ class DecoderBackend:
     into it, device-returning families (jax / pallas) copy their result into
     it — either way the caller's buffer holds the symbols on return, so a
     per-layer decode loop reuses one scratch allocation.
-    ``probe`` answers "can this backend run here at all?" (gates by-name
-    requests); ``auto_probe`` answers "should auto-pick use it here?" — e.g.
-    the jit decoder runs fine on CPU but is only *preferred* when an
-    accelerator is attached, and the interpret fallback is runnable
-    everywhere yet never auto-picked.  ``priority`` orders auto-pick
-    (higher wins).
+    ``probe`` is the rule for "can this backend run here at all?" (gates
+    by-name requests); ``auto_probe`` the rule for "should auto-pick use it
+    here?" — e.g. the jit decoder runs fine on CPU but is only *preferred*
+    when an accelerator is attached, and the interpret fallback is runnable
+    everywhere yet never auto-picked.  ``auto_why`` states that rule for
+    the launcher's report.  ``priority`` orders auto-pick (higher wins).
     """
 
     name: str
@@ -69,14 +75,13 @@ class DecoderBackend:
     probe: Callable[[], bool]
     priority: int = 0
     auto_probe: Optional[Callable[[], bool]] = None
+    auto_why: str = ""
     # the *fused* capability (decode→dequant→matmul in one pass, see
     # kernels/fused_decode_matmul.py): family -> callable
     #   fused_fns[fam](table, x, mat, scale, zero, *, seg_symbols, K, N,
     #                  bits) -> (..., N) activations
-    # probed like compile capability: ``fused_probe`` answers "does the
-    # fused kernel actually run here?" (falls back to ``probe``)
+    # it runs wherever the backend does (``probe``)
     fused_fns: Optional[Mapping[str, Callable]] = None
-    fused_probe: Optional[Callable[[], bool]] = None
 
     @property
     def fn(self) -> Callable[..., np.ndarray]:
@@ -84,28 +89,17 @@ class DecoderBackend:
         return self.fns["prefix"]
 
     def available(self) -> bool:
-        try:
-            return bool(self.probe())
-        except Exception:
-            return False
+        return bool(self.probe())
 
     def auto_eligible(self) -> bool:
-        try:
-            return bool((self.auto_probe or self.probe)())
-        except Exception:
-            return False
+        return bool((self.auto_probe or self.probe)())
 
     def kernel_families(self) -> List[str]:
         return sorted(self.fns)
 
     def fused_available(self) -> bool:
         """Can this backend run the fused decode→dequant→matmul here?"""
-        if not self.fused_fns:
-            return False
-        try:
-            return bool((self.fused_probe or self.probe)())
-        except Exception:
-            return False
+        return bool(self.fused_fns) and self.available()
 
     def fused_families(self) -> List[str]:
         return sorted(self.fused_fns or ())
@@ -178,7 +172,7 @@ def available_backends() -> List[str]:
 
 
 def auto_pick() -> DecoderBackend:
-    """Highest-priority backend whose auto-pick probe passes on this host."""
+    """Highest-priority backend whose auto rule holds on this host."""
     ranked = sorted(_REGISTRY.values(), key=lambda b: -b.priority)
     for b in ranked:
         if b.auto_eligible():
@@ -186,11 +180,24 @@ def auto_pick() -> DecoderBackend:
     return _REGISTRY["numpy"]    # always available by construction
 
 
-def get_backend(name: Optional[str] = None) -> DecoderBackend:
-    """Resolve a backend by name; ``None`` / ``"auto"`` -> capability pick.
+def auto_pick_reason() -> str:
+    """Which backend ``auto`` picks here and why: the picked backend's rule
+    and the rule of every higher-priority backend it passed over."""
+    import jax
+    picked = auto_pick()
+    skipped = [f"{b.name} skipped ({b.auto_why})"
+               for b in sorted(_REGISTRY.values(), key=lambda b: -b.priority)
+               if b.priority > picked.priority]
+    return "; ".join([f"platform {jax.default_backend()}", *skipped,
+                      f"{picked.name} picked ({picked.auto_why})"])
 
-    Asking for an unavailable backend raises so misconfiguration is loud;
-    use ``auto`` when a silent fallback is wanted.
+
+def get_backend(name: Optional[str] = None) -> DecoderBackend:
+    """Resolve a backend by name; ``None`` / ``"auto"`` -> the rule-based
+    pick (:func:`auto_pick`).
+
+    Asking for a backend that cannot run here raises so misconfiguration is
+    loud.
     """
     if name is None or name == "auto":
         return auto_pick()
@@ -245,11 +252,23 @@ def _fused_impl(impl: str):
     return fn
 
 
-def _fused_pallas_supported() -> bool:
-    # keyed on the prefix kernel, mirroring _pallas_supported; the tans
-    # kernel carries its own probe inside fused_supported("tans")
-    from repro.kernels.fused_decode_matmul import fused_supported
-    return fused_supported("prefix")
+# What the TPU compiler accepts, as a rule in code rather than a probe that
+# swallows compiler errors.  Mosaic refuses the per-lane LUT gathers of every
+# Pallas decode kernel (plain and fused, prefix and tANS) on v5e, while the
+# jit decoders compile; tests/test_tpu_compile.py pins both sides against a
+# described v5e topology (the Pallas refusals as strict xfails, so a kernel
+# that starts to lower fails that test until this flag is flipped).
+PALLAS_DECODE_LOWERS = False
+
+
+def on_tpu() -> bool:
+    import jax
+    return jax.default_backend() == "tpu"
+
+
+def pallas_auto_eligible() -> bool:
+    """Auto rule shared by the decode backend and the fused-impl default."""
+    return PALLAS_DECODE_LOWERS and on_tpu()
 
 
 # ------------------------------------------------------------------ numpy
@@ -268,15 +287,11 @@ register_backend(DecoderBackend(
     name="numpy",
     fns={"prefix": _numpy_decode, "tans": _numpy_decode_tans},
     probe=lambda: True, priority=0,
+    auto_why="the host decoder, auto when no backend above qualifies",
     fused_fns={"prefix": _fused_ref, "tans": _fused_ref}))
 
 
 # -------------------------------------------------------------------- jax
-def _jax_ok() -> bool:
-    import jax  # noqa: F401  (baked into the image; probe stays cheap)
-    return True
-
-
 def _jax_accelerated() -> bool:
     import jax
     return jax.default_backend() != "cpu"
@@ -309,20 +324,12 @@ def _jax_decode_tans(mat, counts, tab_sym, tab_bits, tab_base, table_log,
 register_backend(DecoderBackend(
     name="jax",
     fns={"prefix": _jax_decode, "tans": _jax_decode_tans},
-    probe=_jax_ok, priority=10, auto_probe=_jax_accelerated,
+    probe=lambda: True, priority=10, auto_probe=_jax_accelerated,
+    auto_why="auto when an accelerator is attached",
     fused_fns={"prefix": _fused_impl("jax"), "tans": _fused_impl("jax")}))
 
 
 # ----------------------------------------------------------------- pallas
-def _pallas_supported() -> bool:
-    # availability keyed on the prefix kernel alone (the pre-registry
-    # contract): a host that compiles huffman but not the newer tANS kernel
-    # keeps its working 'pallas' prefix decode; the tans fn below probes its
-    # own kernel and fails loudly with a named fallback if it cannot compile
-    from repro.kernels.huffman_decode import pallas_decode_supported
-    return pallas_decode_supported()
-
-
 def _pallas_decode(interpret: bool):
     def fn(mat, counts, lut_sym, lut_len, max_len, max_count, out=None):
         import jax.numpy as jnp
@@ -341,23 +348,9 @@ def _pallas_decode(interpret: bool):
 def _pallas_decode_tans(interpret: bool):
     def fn(mat, counts, tab_sym, tab_bits, tab_base, table_log, max_count,
            out=None):
-        import warnings
-
         import jax.numpy as jnp
-        from repro.kernels.ans_decode import (decode_streams_tans_pallas,
-                                              tans_decode_supported)
+        from repro.kernels.ans_decode import decode_streams_tans_pallas
         from .decode_jax import bucket_streams
-        if not interpret and not tans_decode_supported():
-            # availability is keyed on the prefix kernel, so auto may route a
-            # rans container here on a host where only the tANS kernel fails
-            # to compile: honor auto's silent-fallback contract (and spare a
-            # by-name user a crash) by delegating to the jit tans loop
-            warnings.warn(
-                "the pallas backend's tANS kernel does not compile on this "
-                "host; falling back to the jit tans decoder for this call",
-                stacklevel=2)
-            return _jax_decode_tans(mat, counts, tab_sym, tab_bits, tab_base,
-                                    table_log, max_count, out=out)
         rows = mat.shape[0]
         mat, counts, mc = bucket_streams(mat, counts, max_count)
         res = decode_streams_tans_pallas(
@@ -373,10 +366,13 @@ register_backend(DecoderBackend(
     name="pallas",
     fns={"prefix": _pallas_decode(interpret=False),
          "tans": _pallas_decode_tans(interpret=False)},
-    probe=_pallas_supported, priority=20,
+    # compiled Pallas exists only on a TPU; named there, a kernel Mosaic
+    # refuses raises the compiler's own error at its first decode
+    probe=on_tpu, priority=20, auto_probe=pallas_auto_eligible,
+    auto_why="auto only on a TPU where Mosaic lowers its per-lane LUT "
+             "gathers (PALLAS_DECODE_LOWERS)",
     fused_fns={"prefix": _fused_impl("pallas"),
-               "tans": _fused_impl("pallas")},
-    fused_probe=_fused_pallas_supported))
+               "tans": _fused_impl("pallas")}))
 
 # Interpret mode re-runs the kernel's Python trace per symbol step — orders of
 # magnitude slower than the numpy path.  Explicit opt-in only (never auto).
@@ -384,7 +380,7 @@ register_backend(DecoderBackend(
     name="pallas-interpret",
     fns={"prefix": _pallas_decode(interpret=True),
          "tans": _pallas_decode_tans(interpret=True)},
-    probe=_jax_ok, priority=-10, auto_probe=lambda: False,
+    probe=lambda: True, priority=-10, auto_probe=lambda: False,
+    auto_why="never auto: interpret mode runs only when named",
     fused_fns={"prefix": _fused_impl("pallas-interpret"),
-               "tans": _fused_impl("pallas-interpret")},
-    fused_probe=_jax_ok))
+               "tans": _fused_impl("pallas-interpret")}))

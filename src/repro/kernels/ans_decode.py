@@ -69,34 +69,6 @@ def _tans_kernel(mat_ref, counts_ref, sym_ref, bits_ref, base_ref, out_ref, *,
     out_ref[...] = out
 
 
-def tans_decode_supported(table_log: int = 8) -> bool:
-    """Probe whether the tANS kernel *compiles* on this host (same protocol as
-    ``huffman_decode.pallas_decode_supported``: tiny real decode, cached)."""
-    key = int(table_log)
-    if key in _SUPPORTED_CACHE:
-        return _SUPPORTED_CACHE[key]
-    try:
-        import numpy as np
-        from repro.core.codecs.rans import RansCodeTable
-        table = RansCodeTable(np.array([3, 1], dtype=np.int64), bits=1,
-                              table_log=table_log)
-        syms = np.array([1, 0, 0], np.uint8)
-        stream, _ = table.encode(syms)
-        out = decode_streams_tans_pallas(
-            jnp.asarray(stream[None, :]), jnp.asarray([3], jnp.int32),
-            jnp.asarray(table.tab_sym), jnp.asarray(table.tab_bits),
-            jnp.asarray(table.tab_base),
-            table_log=table.table_log, max_count=3, interpret=False)
-        ok = bool((np.asarray(out)[0] == syms).all())
-    except Exception:
-        ok = False
-    _SUPPORTED_CACHE[key] = ok
-    return ok
-
-
-_SUPPORTED_CACHE: dict = {}
-
-
 @functools.partial(jax.jit,
                    static_argnames=("table_log", "max_count", "interpret"))
 def decode_streams_tans_pallas(mat: jax.Array, counts: jax.Array,

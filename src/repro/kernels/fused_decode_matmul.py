@@ -18,7 +18,7 @@ are written with fixed segment budgets, so real stacked tensors satisfy
 this whenever ``K*N % seg == 0`` (ragged tails fall back to the unfused
 per-layer decode path).
 
-Implementations (``FusedQT.impl``, probed like decode-backend capability):
+Implementations (``FusedQT.impl``, picked by the decode-backend rule):
 
 * ``"jax"`` — in-graph :func:`repro.core.decode_jax.decode_streams_jax` /
   ``decode_streams_tans_jax`` followed by the *exact* ops of
@@ -28,8 +28,9 @@ Implementations (``FusedQT.impl``, probed like decode-backend capability):
   (``tests/differential/``) asserts end to end.
 * ``"pallas"`` — one kernel: grid over K-tiles, each program decodes its
   lane block with the lock-step loop (prefix or tANS), dequantizes in
-  bf16 inside VMEM, and accumulates into an f32 scratch.  Compiled-only;
-  :func:`fused_supported` probes it like ``pallas_decode_supported``.
+  bf16 inside VMEM, and accumulates into an f32 scratch.  Compiled-only,
+  and not yet lowered by Mosaic on v5e (``tests/test_tpu_compile.py``), so
+  :func:`default_fused_impl` never picks it today.
 * ``"pallas-interpret"`` — the same kernel interpreted (CPU differential
   testing only; never auto-picked).
 
@@ -43,7 +44,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -116,10 +116,13 @@ class FusedQT:
                 f"impl={self.impl!r})")
 
 
-def default_fused_impl(family: str = "prefix") -> str:
-    """Capability pick, mirroring the decode-backend registry's auto rule:
-    the compiled Pallas kernel where it probes, the jit path elsewhere."""
-    return "pallas" if fused_supported(family) else "jax"
+def default_fused_impl() -> str:
+    """The decode-backend registry's auto rule applied to the fused path:
+    the compiled Pallas kernel only where Mosaic lowers it
+    (``decode_backends.PALLAS_DECODE_LOWERS``, on a TPU), the jit path
+    everywhere else."""
+    from repro.core.decode_backends import pallas_auto_eligible
+    return "pallas" if pallas_auto_eligible() else "jax"
 
 
 def build_fused_qt(table, mat, scale, zero, *, seg_symbols: int, K: int,
@@ -152,7 +155,7 @@ def build_fused_qt(table, mat, scale, zero, *, seg_symbols: int, K: int,
     else:
         raise ValueError(f"unknown kernel family {table.kernel!r}")
     if impl is None:
-        impl = default_fused_impl(table.kernel)
+        impl = default_fused_impl()
     if impl not in FUSED_IMPLS:
         raise ValueError(f"unknown fused impl {impl!r}; one of {FUSED_IMPLS}")
     return FusedQT(mat, tabs, jnp.asarray(scale), jnp.asarray(zero),
@@ -351,49 +354,3 @@ def fused_decode_matmul(x: jax.Array, fq: FusedQT) -> jax.Array:
     if fq.impl == "pallas-interpret":
         return _fused_pallas(x, fq, interpret=True)
     raise ValueError(f"unknown fused impl {fq.impl!r}; one of {FUSED_IMPLS}")
-
-
-# -------------------------------------------------------------------- probes
-
-_FUSED_CACHE: dict = {}
-
-
-def _probe_case(family: str):
-    """A small but tile-shaped case (N=128 so the compiled kernel sees a
-    full-lane minor dim): returns (x, FusedQT-without-impl args)."""
-    from repro.core.bitstream import GUARD_BYTES, pack_streams, pow2_bucket
-    from repro.core.codecs import get_codec
-    rng = np.random.default_rng(7)
-    K, N, seg = 16, 128, 128
-    sym = rng.integers(0, 16, K * N).astype(np.uint8)
-    freqs = np.bincount(sym, minlength=256).astype(np.int64)
-    codec = "huffman" if family == "prefix" else "rans"
-    table = get_codec(codec).build(freqs, 8, max_code_len=12)
-    streams = [table.encode(sym[i: i + seg])[0]
-               for i in range(0, sym.size, seg)]
-    width = pow2_bucket(max(GUARD_BYTES, max(s.size for s in streams)), 64)
-    mat, _ = pack_streams(streams, min_width=width)
-    x = jnp.asarray(rng.normal(size=(16, K)), jnp.bfloat16)
-    scale = np.float32(0.01) * np.ones((1, 1), np.float32)
-    zero = np.zeros((1, 1), np.float32)
-    return x, table, mat, scale, zero, seg, K, N
-
-
-def fused_supported(family: str = "prefix") -> bool:
-    """Probe whether the fused kernel *compiles* on this host (the ``fused``
-    capability the backend registry reports): runs the probe case with
-    ``interpret=False`` and checks the result against the jit path.  Cached
-    after the first call, like ``pallas_decode_supported``."""
-    if family in _FUSED_CACHE:
-        return _FUSED_CACHE[family]
-    try:
-        x, table, mat, scale, zero, seg, K, N = _probe_case(family)
-        fq = build_fused_qt(table, mat, scale, zero, seg_symbols=seg, K=K,
-                            N=N, bits=8, impl="pallas")
-        got = np.asarray(_fused_pallas(x, fq, interpret=False), np.float32)
-        want = np.asarray(_fused_jax(x, fq), np.float32)
-        ok = np.allclose(got, want, atol=1e-2, rtol=1e-2)
-    except Exception:
-        ok = False
-    _FUSED_CACHE[family] = ok
-    return ok

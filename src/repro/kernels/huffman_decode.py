@@ -65,40 +65,6 @@ def _decode_kernel(mat_ref, counts_ref, lut_sym_ref, lut_len_ref, out_ref, *,
     out_ref[...] = out
 
 
-def pallas_decode_supported(max_len: int = 8) -> bool:
-    """Probe whether the decode kernel *compiles* on this host.
-
-    Runs a one-stream, one-symbol decode with ``interpret=False`` and checks
-    the result; any lowering/compile error (e.g. CPU-only hosts, where Pallas
-    has no compiled path) makes this False.  Cached after first call — the
-    backend registry consults it so ``interpret=True`` is never picked
-    implicitly (it is the explicitly named ``pallas-interpret`` fallback).
-    """
-    key = int(max_len)
-    if key in _SUPPORTED_CACHE:
-        return _SUPPORTED_CACHE[key]
-    try:
-        import numpy as np
-        from repro.core.bitstream import encode_symbols
-        from repro.core.entropy import HuffmanTable
-        table = HuffmanTable(np.array([1, 1], dtype=np.int64), max_len=max_len)
-        stream, _ = encode_symbols(np.array([1], np.uint8), table.codes,
-                                   table.lengths)
-        mat = stream[None, :]
-        out = decode_streams_pallas(
-            jnp.asarray(mat), jnp.asarray([1], jnp.int32),
-            jnp.asarray(table.lut_sym), jnp.asarray(table.lut_len),
-            max_len=max_len, max_count=1, interpret=False)
-        ok = int(np.asarray(out)[0, 0]) == 1
-    except Exception:
-        ok = False
-    _SUPPORTED_CACHE[key] = ok
-    return ok
-
-
-_SUPPORTED_CACHE: dict = {}
-
-
 @functools.partial(jax.jit,
                    static_argnames=("max_len", "max_count", "interpret"))
 def decode_streams_pallas(mat: jax.Array, counts: jax.Array, lut_sym: jax.Array,

@@ -1,32 +1,18 @@
 """Jitted public wrappers around the Pallas kernels + packing utilities.
 
-``interpret=None`` resolves by capability probe: the compiled kernel is used
-whenever it lowers on this host (``huffman_decode.pallas_decode_supported``),
-and interpret mode is only the fallback when compilation is impossible
-(CPU-only containers); callers can force either.  All wrappers fall back to
-the jnp oracle when ``REPRO_DISABLE_PALLAS=1`` (escape hatch for debugging).
+The wrappers run the compiled kernel unless the caller names interpret mode
+(``interpret=True``); a kernel the compiler refuses raises its error.
 """
 from __future__ import annotations
 
-import os
-from functools import partial
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import ref
 from .dequant_matmul import dequant_matmul as _dequant_matmul_pallas
-from .huffman_decode import decode_streams_pallas, pallas_decode_supported
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _use_pallas() -> bool:
-    return os.environ.get("REPRO_DISABLE_PALLAS", "0") != "1"
+from .huffman_decode import decode_streams_pallas
 
 
 def pack_nibbles(q: np.ndarray) -> np.ndarray:
@@ -46,12 +32,9 @@ def unpack_nibbles(p: np.ndarray) -> np.ndarray:
 
 
 def dequant_matmul(x: jax.Array, wq: jax.Array, scale, zero, *,
-                   int4: bool = False, interpret: Optional[bool] = None,
+                   int4: bool = False, interpret: bool = False,
                    block: Tuple[int, int, int] = (128, 128, 512)) -> jax.Array:
     """Fused dequant matmul with automatic padding to block multiples."""
-    if not _use_pallas():
-        return ref.dequant_matmul_ref(x, wq, scale, zero, int4=int4)
-    interpret = (not _on_tpu()) if interpret is None else interpret
     bm, bn, bk = block
     M, K = x.shape
     N = wq.shape[1]
@@ -73,15 +56,8 @@ def dequant_matmul(x: jax.Array, wq: jax.Array, scale, zero, *,
 
 def huffman_decode(mat: jax.Array, counts: jax.Array, lut_sym: jax.Array,
                    lut_len: jax.Array, *, max_len: int, max_count: int,
-                   interpret: Optional[bool] = None) -> jax.Array:
+                   interpret: bool = False) -> jax.Array:
     """Multi-stream Huffman decode (see kernels.huffman_decode)."""
-    if not _use_pallas():
-        import numpy as _np
-        return jnp.asarray(ref.decode_streams_ref(
-            _np.asarray(mat), _np.asarray(counts), _np.asarray(lut_sym),
-            _np.asarray(lut_len), max_len))
-    if interpret is None:
-        interpret = not pallas_decode_supported()
     return decode_streams_pallas(mat, counts, lut_sym, lut_len,
                                  max_len=max_len, max_count=max_count,
                                  interpret=interpret)

@@ -1,6 +1,6 @@
-"""Mesh construction + the jax-version compat shim.
+"""Mesh construction.
 
-A FUNCTION (not a module-level constant) so importing this module never
+FUNCTIONS (not module-level constants) so importing this module never
 touches jax device state — device count is locked at first jax init, and only
 ``dryrun.py`` sets the 512-device XLA flag.
 
@@ -12,63 +12,30 @@ Axis roles (DESIGN.md §6): "model" = TP + EP; "data" = FSDP + batch DP;
 "pod" = hierarchical DP (gradient all-reduce over DCI; weights replicated
 per pod so only grads cross pods).
 
-Compat: ``jax.sharding.AxisType`` (and the ``axis_types=`` kwarg of
-``jax.make_mesh``) only exists on newer jax.  On jax 0.4.x the attribute
-lookup raises, which used to kill every mesh construction in the repo.
-:func:`make_mesh` is the one place that knows the difference — every mesh in
-src/ and tests/ goes through it: Auto axis types where the API has them,
-positional fallback (plain ``jax.make_mesh``) where it doesn't.
+Every mesh is built with Auto axis types: the repo's sharding contract is
+GSPMD propagation from operand shardings, stated rather than left to
+``jax.make_mesh``'s default.
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import Tuple
 
 import jax
-
-# None on jax without the explicit-sharding API (e.g. 0.4.37); the enum on
-# newer jax.  Resolved once at import — the API surface cannot change mid-run.
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
-
-def auto_axis_types(n: int) -> Optional[tuple]:
-    """``(AxisType.Auto,) * n`` on new jax; None where the enum is absent."""
-    if _AXIS_TYPE is None:
-        return None
-    return (_AXIS_TYPE.Auto,) * n
-
-
-def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
-              devices=None) -> jax.sharding.Mesh:
-    """Version-portable ``jax.make_mesh``.
-
-    On jax with ``jax.sharding.AxisType`` the mesh is built with explicit
-    Auto axis types (the repo's GSPMD-propagation contract stated, not
-    inferred); on 0.4.x the kwarg does not exist and the positional call is
-    used — 0.4.x meshes are implicitly Auto, so behavior is identical.
-    """
-    shape, names = tuple(axis_shapes), tuple(axis_names)
-    kw = {} if devices is None else {"devices": devices}
-    at = auto_axis_types(len(names))
-    if at is not None:
-        try:
-            return jax.make_mesh(shape, names, axis_types=at, **kw)
-        except TypeError:
-            # AxisType exists but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(shape, names, **kw)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(*, model: int = 1):
     """Whatever this host actually has (CPU smoke tests, examples)."""
     n = len(jax.devices())
     assert n % model == 0, (n, model)
-    return make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def parse_mesh_spec(spec: str) -> Tuple[int, int]:
@@ -97,7 +64,8 @@ def make_serve_mesh(data: int, model: int) -> jax.sharding.Mesh:
             f"--mesh {data}x{model} needs {need} devices but this host has "
             f"{len(devs)}; force CPU devices with "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={need}")
-    return make_mesh((data, model), ("data", "model"), devices=devs[:need])
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2, devices=devs[:need])
 
 
 # TPU v5e hardware constants used by every roofline computation.

@@ -30,6 +30,18 @@ Two serving modes:
       PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b \
           --bits 8 --batch-slots 8 --traffic 16 --gen 16
 
+By default the launcher serves the registry's reduced smoke config
+(d_model 128, vocab 512).  ``--published-layers N`` serves the published
+config instead, every width as published and depth cut to N whole layers
+(``chip_smoke.py`` drives Qwen3-1.7B this way on one chip):
+
+      PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b \
+          --published-layers 4 --batch 4 --prompt-len 64 --gen 16
+
+``main`` runs the steps ``model_config`` -> ``build`` -> ``compress`` ->
+``load`` -> ``serve_*``, which ``chip_smoke.py`` calls one by one to serve
+several phases from one container.
+
 ``--production`` lowers the full-config serve_step on the production mesh
 instead (same path as the dry-run decode cells).
 """
@@ -39,9 +51,17 @@ import sys
 import time
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
+    """Parse and validate the launcher's flags.  Every incompatible flag
+    combination fails here, before any expensive work; the parsed
+    ``compress_spec`` / ``kv_spec`` / ``mesh_dims`` / ``disaggregate_split``
+    ride on the returned namespace."""
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--arch", required=True)
+    p.add_argument("--published-layers", type=int, default=0, metavar="N",
+                   help="serve the registry's published config at every "
+                        "published width, cut to N whole layers (default 0: "
+                        "the reduced smoke config, d_model 128, vocab 512)")
     p.add_argument("--bits", type=int, default=8,
                    help="uniform quantization bit-width, 1..8 (subsumed by "
                         "--compress-spec)")
@@ -150,13 +170,20 @@ def main(argv=None):
     p.add_argument("--multi-pod", action="store_true")
     args = p.parse_args(argv)
 
-    mesh_dims = None
+    args.mesh_dims = None
     if args.mesh:
         from repro.launch.mesh import parse_mesh_spec
         try:
-            mesh_dims = parse_mesh_spec(args.mesh)
+            args.mesh_dims = parse_mesh_spec(args.mesh)
         except ValueError as e:
             p.error(f"--mesh: {e}")
+
+    if args.published_layers:
+        from repro.configs import registry
+        try:
+            registry.published(registry.get(args.arch), args.published_layers)
+        except (KeyError, ValueError) as e:
+            p.error(f"--published-layers: {e}")
 
     if args.resident == "compressed":
         # same upfront-validation contract as the other flags: incompatible
@@ -179,7 +206,7 @@ def main(argv=None):
 
     # paged KV: parse + validate the policy upfront (same contract as
     # --compress-spec); the paged pool rides dense residency, single device
-    kv_spec = None
+    kv_spec = args.kv_spec_parsed = None
     if args.kv_spec is not None or args.kv_block or args.prefix_sharing:
         if args.batch_slots <= 0:
             p.error("--kv-spec/--kv-block/--prefix-sharing require "
@@ -202,6 +229,7 @@ def main(argv=None):
             kv_spec = KVCompressionSpec.parse(args.kv_spec or "", **overrides)
         except (ValueError, KeyError) as e:
             p.error(f"bad --kv-spec: {e}")
+        args.kv_spec_parsed = kv_spec
         if kv_spec.sharing and args.prefill_chunk % kv_spec.block_size:
             p.error(f"--prefix-sharing needs --prefill-chunk divisible by "
                     f"the KV block size (chunk {args.prefill_chunk}, block "
@@ -291,33 +319,87 @@ def main(argv=None):
             legacy_kw["segment_symbols"] = 4096
         compress_spec = spec_from_legacy(args.bits, Granularity.PER_CHANNEL,
                                          codec=args.codec, **legacy_kw)
+    args.compress_spec_parsed = compress_spec
+    return args
 
+
+def main(argv=None):
+    args = parse_args(argv)
     if args.production:
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
         from repro.launch import dryrun
         d = dryrun.run_cell(args.arch, args.shape, multi_pod=args.multi_pod)
         return 0 if "error" not in d else 1
 
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from repro.configs import registry
-    from repro.core.store import CompressedModel
-    from repro.models import api
-    from repro.obs import metrics as obs_metrics
+    from repro.launch.compile_cache import setup_compile_cache
     from repro.obs import trace as obs_trace
-    from repro.serving import engine
-
+    setup_compile_cache()
     if args.trace_out or args.trace_sync:
         obs_trace.enable(sync=args.trace_sync)
 
-    cfg = registry.reduced(registry.get(args.arch))
-    mod = api.build(cfg)
-    params = mod.init(cfg, jax.random.PRNGKey(0))
-    host = {k: np.asarray(v, np.float32) for k, v in params.items()}
+    cfg = model_config(args)
+    mesh = rules = None
+    if args.mesh_dims is not None:
+        from repro.launch import mesh as mesh_lib
+        from repro.serving import engine
+        try:
+            mesh = mesh_lib.make_serve_mesh(*args.mesh_dims)
+        except ValueError as e:
+            print(f"serve: error: {e}", file=sys.stderr)
+            return 2
+        rules = engine.serve_mesh_rules(cfg, mesh)
+    cm = compress(build(cfg), args.compress_spec_parsed)
+    serve_params, load_metrics = load(cm, cfg, args, mesh=mesh)
+    sc, kv_prefix_len = serve_config(cfg, args)
 
+    rc = 0
+    if args.batch_slots > 0 and args.replicas > 0:
+        fd = serve_fleet(cfg, serve_params, sc, args, load_metrics,
+                         kv_prefix_len=kv_prefix_len)
+        rc = 0 if fd.finished else 1
+    elif args.batch_slots > 0:
+        ce = serve_continuous(cfg, serve_params, sc, args, load_metrics,
+                              mesh=mesh, rules=rules,
+                              kv_prefix_len=kv_prefix_len)
+        rc = 0 if ce.finished else 1
+    else:
+        serve_lockstep(cfg, serve_params, sc, args, load_metrics, mesh=mesh,
+                       rules=rules)
+    _write_obs(args)
+    return rc
+
+
+def model_config(args):
+    """The config ``args`` serves: the reduced smoke variant by default,
+    the published widths cut to ``--published-layers`` layers otherwise
+    (the cut is printed)."""
+    from repro.configs import registry
+    base = registry.get(args.arch)
+    if not args.published_layers:
+        return registry.reduced(base)
+    cfg = registry.published(base, args.published_layers)
+    print(f"{base.name} at published widths: {cfg.n_layers} of "
+          f"{base.n_layers} layers kept (d_model {cfg.d_model}, "
+          f"{cfg.n_heads} heads / {cfg.n_kv_heads} KV heads, head_dim "
+          f"{cfg.hd}, d_ff {cfg.d_ff}, vocab {cfg.vocab})")
+    return cfg
+
+
+def build(cfg, seed: int = 0):
+    """Seeded random weights for ``cfg``: a host float32 copy of every
+    parameter, the input :func:`compress` takes."""
+    import jax
+    import numpy as np
+    from repro.models import api
+    params = api.build(cfg).init(cfg, jax.random.PRNGKey(seed))
+    return {k: np.asarray(v, np.float32) for k, v in params.items()}
+
+
+def compress(host, spec):
+    """Quantize + entropy-code ``host`` under ``spec``; prints the stats."""
+    from repro.core.store import CompressedModel
     t0 = time.perf_counter()
-    cm = CompressedModel.compress(host, spec=compress_spec)
+    cm = CompressedModel.compress(host, spec=spec)
     t_comp = time.perf_counter() - t0
     st = cm.stats()
     print(f"compressed {st.param_count/1e6:.1f}M params: "
@@ -329,16 +411,18 @@ def main(argv=None):
         print(f"  [{g.table_id}] {g.param_count/1e6:.2f}M params: "
               f"{g.bits}b {g.codec} -> {g.effective_bits:.2f} achieved bits "
               f"(bound {g.entropy_bits:.2f}, {g.shannon_ratio:.3f}x)")
+    return cm
 
-    mesh = rules = None
-    if mesh_dims is not None:
-        from repro.launch import mesh as mesh_lib
-        try:
-            mesh = mesh_lib.make_serve_mesh(*mesh_dims)
-        except ValueError as e:
-            p.error(str(e))
-        rules = engine.serve_mesh_rules(cfg, mesh)
 
+def load(cm, cfg, args, mesh=None):
+    """Load ``cm`` into serving weights the way ``args`` asks (dense or
+    compressed residency, streamed or monolithic, optionally placed on
+    ``mesh``); prints the decoder pick and the load.  Returns
+    ``(serve_params, load_metrics)``."""
+    from repro.core.decode_backends import auto_pick_reason
+    from repro.serving import engine
+    if args.decode_backend in (None, "auto"):
+        print(f"decoder backend auto: {auto_pick_reason()}")
     load_metrics = {}
     load_kw = {}
     if args.chunk_symbols is not None:      # absent flag -> scheduler default
@@ -360,6 +444,12 @@ def main(argv=None):
         if args.fused:
             impls = sorted({fq.impl for slots in serve_params._fused_slots
                             for fq in slots.values()})
+            if args.fused_impl is None:
+                from repro.kernels.fused_decode_matmul import \
+                    default_fused_impl
+                print(f"  fused impl auto: {default_fused_impl()} (pallas "
+                      f"only on a TPU where Mosaic lowers the fused kernel, "
+                      f"decode_backends.PALLAS_DECODE_LOWERS)")
             print(f"  fused decode→dequant→matmul: "
                   f"{len(serve_params._fused)} tensors "
                   f"{sorted(serve_params._fused)} via {impls or ['-']}; "
@@ -396,11 +486,19 @@ def main(argv=None):
     if mesh is not None:
         pb = engine.per_device_bytes(serve_params)
         lo, hi = min(pb.values()), max(pb.values())
-        print(f"mesh {mesh_dims[0]}x{mesh_dims[1]} (data x model): weights "
-              f"placed over {len(pb)} devices, "
+        print(f"mesh {args.mesh_dims[0]}x{args.mesh_dims[1]} (data x model): "
+              f"weights placed over {len(pb)} devices, "
               f"{lo/2**20:.1f}-{hi/2**20:.1f} MiB/device "
               f"({sum(pb.values())/2**20:.1f} MiB total)")
+    return serve_params, load_metrics
 
+
+def serve_config(cfg, args):
+    """The ServeConfig ``args`` needs, and the shared-prefix length its
+    traffic uses; prints the KV-cache footprint."""
+    from repro.models import api
+    from repro.serving import engine
+    kv_spec = args.kv_spec_parsed
     # slot mode pads prompts to a prefill-chunk multiple, so its cache needs
     # that much headroom; the lockstep path keeps the exact footprint.
     # Prefix-shared traffic prepends a block-aligned system prompt, so the
@@ -412,26 +510,28 @@ def main(argv=None):
     prompt_budget = max(args.prompt_len, kv_prefix_len + 1)
     headroom = max(args.prefill_chunk, 0) if args.batch_slots > 0 else 0
     sc = engine.ServeConfig(max_len=prompt_budget + args.gen + headroom)
-    rng = np.random.default_rng(0)
 
     # true serving peak is weights + KV — surface the KV term the weight
     # breakdowns above leave out (paged pool bytes print with the manager's
     # own numbers inside _serve_continuous)
-    if kv_spec is None and hasattr(mod, "init_cache"):
+    if kv_spec is None and hasattr(api.build(cfg), "init_cache"):
         from repro.serving.kvcache import kv_cache_bytes
         kv_rows = args.batch_slots if args.batch_slots > 0 else args.batch
         kvb = kv_cache_bytes(cfg, kv_rows, sc.max_len)
         print(f"  KV cache {kvb/2**20:.2f} MiB resident "
               f"({kv_rows} x {sc.max_len} bf16 rows) — true serving peak = "
               f"weights + KV")
+    return sc, kv_prefix_len
 
-    if args.batch_slots > 0:
-        rc = _serve_continuous(cfg, serve_params, sc, args, rng,
-                               load_metrics, mesh=mesh, rules=rules,
-                               kv_spec=kv_spec, kv_prefix_len=kv_prefix_len)
-        _write_obs(args)
-        return rc
 
+def serve_lockstep(cfg, serve_params, sc, args, load_metrics, mesh=None,
+                   rules=None):
+    """Lockstep mode: one seeded batch through ``Engine.generate``.
+    Returns ``(engine, prompt, tokens)``."""
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.serving import engine
+    rng = np.random.default_rng(0)
     eng = engine.Engine(cfg, serve_params, sc, mesh=mesh, rules=rules,
                         resident=args.resident)
     if cfg.family == "encdec":
@@ -454,8 +554,7 @@ def main(argv=None):
           f"({metrics['decode_tok_per_s']:.1f} decode tok/s, "
           f"{metrics['e2e_tok_per_s']:.1f} e2e tok/s); "
           f"time-to-first-token incl. weight load: {ttft:.2f}s")
-    _write_obs(args)
-    return 0
+    return eng, prompt, out
 
 
 def _write_obs(args):
@@ -474,18 +573,18 @@ def _write_obs(args):
         print(f"metrics: {n} rows -> {args.metrics_out}")
 
 
-def _serve_continuous(cfg, serve_params, sc, args, rng, load_metrics,
-                      mesh=None, rules=None, kv_spec=None, kv_prefix_len=0):
-    """--batch-slots path: slot-batched serving of independent requests."""
+def serve_continuous(cfg, serve_params, sc, args, load_metrics, mesh=None,
+                     rules=None, kv_prefix_len=0):
+    """--batch-slots path: slot-batched serving of independent requests.
+    Returns the :class:`ContinuousEngine` after it drained (its
+    ``finished`` requests are the result)."""
     import numpy as np
     from repro.obs.metrics import percentile
     from repro.serving.batching import (ContinuousEngine, QueueFullError,
                                         poisson_trace, replay)
 
-    if args.replicas > 0:
-        return _serve_fleet(cfg, serve_params, sc, args, load_metrics,
-                            kv_spec=kv_spec, kv_prefix_len=kv_prefix_len)
-
+    kv_spec = args.kv_spec_parsed
+    rng = np.random.default_rng(0)
     ce = ContinuousEngine(cfg, serve_params, sc, n_slots=args.batch_slots,
                           max_queue=args.max_queue,
                           prefill_chunk=args.prefill_chunk,
@@ -525,7 +624,7 @@ def _serve_continuous(cfg, serve_params, sc, args, rng, load_metrics,
     if not fin:
         print(f"continuous batching: no requests completed "
               f"({shed} shed by backpressure)")
-        return 1
+        return ce
     toks = sum(len(r.output) for r in fin)
     lat = [r.latency_s for r in fin]
     ttft = [r.ttft_s for r in fin]
@@ -551,27 +650,33 @@ def _serve_continuous(cfg, serve_params, sc, args, rng, load_metrics,
               f"tier {st['cold_bytes']/2**10:.1f} KiB "
               f"({st['cold_evictions']} evictions, {st['cold_restores']} "
               f"restores, {st['dropped_evictions']} dropped)")
-    return 0
+    return ce
 
 
-def _serve_fleet(cfg, serve_params, sc, args, load_metrics,
-                 kv_spec=None, kv_prefix_len=0):
-    """--replicas path: DP fleet of continuous engines behind the router.
+def serve_fleet(cfg, serve_params, sc, args, load_metrics, kv_prefix_len=0,
+                threaded=False):
+    """--replicas path: DP fleet of continuous engines behind the router,
+    one replica per local device (replicas wrap around when there are more
+    replicas than devices).  Returns the :class:`FleetDriver` after it
+    drained.
 
-    Lockstep drive (docs/FLEET.md §"Drive modes") — deterministic and
-    per-request bit-identical to a single engine; the threaded mode is the
-    fleet benchmark's job, not the launcher's.
+    The launcher drives in lockstep (docs/FLEET.md §"Drive modes") —
+    deterministic and per-request bit-identical to a single engine;
+    ``threaded=True`` gives each replica its own stepping thread.
     """
+    import jax
     from repro.obs.metrics import percentile
     from repro.serving.batching import poisson_trace, replay_fleet
     from repro.serving.fleet import FleetDriver
 
+    kv_spec = args.kv_spec_parsed
     split = args.disaggregate_split
     fd = FleetDriver(cfg, serve_params, sc, n_replicas=args.replicas,
                      policy=args.router, n_slots=args.batch_slots,
                      max_queue=args.max_queue,
                      prefill_chunk=args.prefill_chunk,
-                     kv_spec=kv_spec, disaggregate=split)
+                     kv_spec=kv_spec, disaggregate=split,
+                     devices=jax.local_devices()[:args.replicas])
     wb = fd.weight_bytes()
     topo = (f"{split[0]} prefill + {split[1]} decode, disaggregated"
             if split else f"{args.replicas}x data-parallel")
@@ -579,6 +684,8 @@ def _serve_fleet(cfg, serve_params, sc, args, load_metrics,
           f"{wb['copies']} weight cop{'y' if wb['copies'] == 1 else 'ies'} "
           f"resident ({wb['total_bytes']/2**20:.2f} MiB, "
           f"mode {wb['mode']})")
+    print("  replica devices: " + ", ".join(
+        f"r{h.idx} {h.device}" for h in fd.replicas))
     n = args.traffic if args.traffic > 0 else args.batch
     prefix_kw = {}
     if kv_spec is not None and kv_spec.sharing:
@@ -587,13 +694,13 @@ def _serve_fleet(cfg, serve_params, sc, args, load_metrics,
                           gen_max=args.gen, vocab=cfg.vocab, seed=0,
                           **prefix_kw)
     t0 = time.monotonic()
-    _, shed, _ = replay_fleet(fd, trace, shed_on_full=True)
+    replay_fleet(fd, trace, shed_on_full=True, threaded=threaded)
     span = time.monotonic() - t0
     fin = fd.finished
     n_shed = len(fd.shed)
     if not fin:
         print(f"fleet: no requests completed ({n_shed} shed)")
-        return 1
+        return fd
     toks = sum(len(r.output) for r in fin)
     ttft = [r.ttft_s for r in fin]
     lat = [r.latency_s for r in fin]
@@ -615,7 +722,7 @@ def _serve_fleet(cfg, serve_params, sc, args, load_metrics,
         print(f"  handoff: {fd.handoff.n_handoffs} prefill->decode "
               f"payloads, {fd.handoff.bytes_on_wire/2**10:.1f} KiB "
               f"entropy-coded on the wire")
-    return 0
+    return fd
 
 
 if __name__ == "__main__":

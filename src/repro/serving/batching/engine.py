@@ -44,6 +44,7 @@ short.
 from __future__ import annotations
 
 import time
+from functools import partial
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -62,15 +63,16 @@ from .request import Request, RequestState, SamplingParams
 from .slots import SlotBatchManager
 
 
-@jax.jit
-def _sample_slots(logits, keys, temps):
+@partial(jax.jit, static_argnames="vocab")
+def _sample_slots(logits, keys, temps, vocab):
     """Per-slot sampling with per-request PRNG streams.
 
     logits: (B, 1, V); keys: (B, 2) uint32 (one stream per slot, split fresh
-    every step); temps: (B,) f32 — greedy lanes (temp <= 0) ignore their key.
+    every step); temps: (B,) f32 — greedy lanes (temp <= 0) ignore their key;
+    columns from ``vocab`` on (the padded vocabulary) are never sampled.
     Returns (tokens (B,), advanced keys (B, 2)).
     """
-    last = logits[:, -1]
+    last = logits[:, -1, :vocab]
     greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
     ks = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
     cat = jax.vmap(
@@ -266,7 +268,8 @@ class ContinuousEngine:
         else:
             self.slots.insert(slot, st["scratch"], P)
         key, sub = jax.random.split(jax.random.PRNGKey(req.sampling.seed))
-        tok = int(sample(st["last"], sub, req.sampling.temperature)[0])
+        tok = int(sample(st["last"], sub, req.sampling.temperature,
+                         self.cfg.vocab)[0])
         req.t_first_token = time.monotonic()
         obs_metrics.histogram("request.ttft_s").observe(req.ttft_s or 0.0)
         req.state = RequestState.DECODING
@@ -335,7 +338,8 @@ class ContinuousEngine:
                 logits, self.slots.cache = self.steps.decode_fn(
                     self.params, tok, self.slots.cache, pos)
             new_tok, new_keys = _sample_slots(logits, jnp.asarray(self._keys),
-                                              jnp.asarray(self._temps))
+                                              jnp.asarray(self._temps),
+                                              self.cfg.vocab)
             new_tok = np.asarray(new_tok)
         self._keys = np.array(new_keys)     # copy: host mirror stays writable
         self.n_decode_steps += 1

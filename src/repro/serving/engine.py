@@ -223,10 +223,15 @@ def load_params_from_compressed(model: CompressedModel, *,
     return out
 
 
-def sample(logits: jax.Array, key: jax.Array, temperature: float) -> jax.Array:
+def sample(logits: jax.Array, key: jax.Array, temperature: float,
+           vocab: Optional[int] = None) -> jax.Array:
+    """Greedy or temperature-categorical pick from the last position.
+    ``vocab`` drops the padded-vocabulary columns (``cfg.padded_vocab()``
+    rounds the lm head up), so no id outside the vocabulary is emitted."""
+    last = logits[:, -1, :vocab]
     if temperature <= 0.0:
-        return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-    return jax.random.categorical(key, logits[:, -1] / temperature).astype(jnp.int32)
+        return jnp.argmax(last, axis=-1).astype(jnp.int32)
+    return jax.random.categorical(key, last / temperature).astype(jnp.int32)
 
 
 class ServeSteps:
@@ -451,7 +456,7 @@ class ServeSteps:
                 try:
                     # no ambient-mesh context needed: every constraint the
                     # hints drive builds an explicit NamedSharding from
-                    # hints.mesh (works on 0.4.x and new jax alike)
+                    # hints.mesh
                     return fn(*args)
                 finally:
                     set_hints(prev)
@@ -530,7 +535,7 @@ class Engine:
         # first token from the parent key and then re-splitting that same key
         # in the loop would correlate token 0 with token 1
         key, sub = jax.random.split(key)
-        tok = sample(logits, sub, self.sc.temperature)[:, None]
+        tok = sample(logits, sub, self.sc.temperature, self.cfg.vocab)[:, None]
         tok.block_until_ready()
         t_first_token = time.perf_counter() - t0
         toks.append(tok)
@@ -543,7 +548,8 @@ class Engine:
                 key, sub = jax.random.split(key)
                 logits, cache = self.decode_fn(self.params, tok, cache,
                                                jnp.int32(S + i))
-                tok = sample(logits, sub, self.sc.temperature)[:, None]
+                tok = sample(logits, sub, self.sc.temperature,
+                             self.cfg.vocab)[:, None]
                 toks.append(tok)
                 _fence(tok)
             # without --trace-sync each step time is host dispatch (plus any

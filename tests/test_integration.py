@@ -152,9 +152,9 @@ def test_straggler_watchdog_and_rebalance():
 def test_elastic_reshard_restore():
     """Restore a checkpoint onto a different device layout (1-dev host mesh)."""
     from repro.checkpoint.checkpointer import Checkpointer, CheckpointConfig
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.launch import mesh as mesh_lib
-    mesh = mesh_lib.make_mesh((1, 1), ("data", "model"))
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
     with tempfile.TemporaryDirectory() as d:
         ck = Checkpointer(CheckpointConfig(root=d))

@@ -23,7 +23,7 @@ def test_dequant_matmul_int8(M, K, N, per_channel):
         zero = rng.uniform(-1, 0, size=(N,)).astype(np.float32)
     else:
         scale, zero = np.float32(0.005), np.float32(-0.6)
-    out = ops.dequant_matmul(x, wq, scale, zero)
+    out = ops.dequant_matmul(x, wq, scale, zero, interpret=True)
     want = ref.dequant_matmul_ref(x, wq, scale, zero)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), atol=1e-2,
@@ -38,7 +38,8 @@ def test_dequant_matmul_int4(M, K, N):
     packed = jnp.asarray(ops.pack_nibbles(q4))
     scale = rng.uniform(0.01, 0.1, size=(N,)).astype(np.float32)
     zero = np.zeros(N, np.float32)
-    out = ops.dequant_matmul(x, packed, scale, zero, int4=True)
+    out = ops.dequant_matmul(x, packed, scale, zero, int4=True,
+                             interpret=True)
     want = ref.dequant_matmul_ref(x, packed, scale, zero, int4=True)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), atol=1e-2,
@@ -59,7 +60,8 @@ def test_dequant_matmul_equals_float_matmul():
     qt = quant.quantize(w, 8)
     x = jnp.asarray(rng.normal(size=(16, 256)), jnp.bfloat16)
     out = ops.dequant_matmul(x, jnp.asarray(qt.q),
-                             qt.scale.reshape(-1), qt.zero.reshape(-1))
+                             qt.scale.reshape(-1), qt.zero.reshape(-1),
+                             interpret=True)
     want = np.asarray(x, np.float32) @ quant.dequantize(qt)
     np.testing.assert_allclose(np.asarray(out, np.float32), want,
                                atol=0.15, rtol=0.05)
@@ -84,7 +86,7 @@ def test_huffman_decode_kernel_vs_host(n_streams, max_len):
     kern = ops.huffman_decode(
         jnp.asarray(mat), jnp.asarray(counts, jnp.int32),
         jnp.asarray(table.lut_sym), jnp.asarray(table.lut_len),
-        max_len=max_len, max_count=int(counts.max()))
+        max_len=max_len, max_count=int(counts.max()), interpret=True)
     assert (np.asarray(kern) == host).all()
 
 
@@ -103,6 +105,6 @@ def test_huffman_decode_kernel_roundtrip_identity():
     out = ops.huffman_decode(
         jnp.asarray(mat), jnp.asarray(counts, jnp.int32),
         jnp.asarray(table.lut_sym), jnp.asarray(table.lut_len),
-        max_len=12, max_count=int(counts.max()))
+        max_len=12, max_count=int(counts.max()), interpret=True)
     got = np.concatenate([np.asarray(out)[i, :c] for i, c in enumerate(counts)])
     assert (got == syms).all()

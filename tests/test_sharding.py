@@ -1,19 +1,17 @@
 """Sharding-rule resolution logic (pure; no multi-device requirement)."""
 import jax
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.distributed import sharding as shd
-from repro.launch import mesh as mesh_lib
 
 
 @pytest.fixture(scope="module")
 def mesh():
     # single-device 1x1 mesh: resolve_spec only reads axis NAMES and SIZES,
     # so divisibility is exercised with a fake-shape wrapper below.
-    # mesh_lib.make_mesh is the jax-version compat shim (AxisType on new jax,
-    # positional fallback on 0.4.x).
-    return mesh_lib.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 class FakeMesh:
@@ -129,7 +127,8 @@ def test_qt_leaf_shardings_consistent():
     import jax.numpy as jnp
     from jax.sharding import NamedSharding
     from repro.models.layers import QT, QT4
-    m = mesh_lib.make_mesh((1, 1), ("data", "model"))
+    m = jax.make_mesh((1, 1), ("data", "model"),
+                      axis_types=(AxisType.Auto,) * 2)
 
     class FM:        # fake 4x4 shape, real mesh for NamedSharding construction
         shape = {"data": 4, "model": 4}
@@ -167,7 +166,8 @@ def test_qt_follower_per_group_divisibility():
 def test_quantized_param_structs_match_schema():
     from repro.configs import registry
     from repro.launch import specs
-    m = mesh_lib.make_mesh((1, 1), ("data", "model"))
+    m = jax.make_mesh((1, 1), ("data", "model"),
+                      axis_types=(AxisType.Auto,) * 2)
     cfg = registry.get("qwen3-1.7b")
     rules = shd.serve_rules(m)
     for fmt in ("bf16", "int8", "int4"):

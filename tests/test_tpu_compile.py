@@ -1,0 +1,162 @@
+"""Compile the serving path's device programs for a described TPU v5e.
+
+No chip is needed: the TPU compiler that ships with jax compiles against a
+described ``v5e:2x2`` topology, so these tests catch what only the chip's
+compiler refuses (lowering, tiling, memory) at no chip time.  Shapes are
+the ones ``chip_smoke.py`` drives at Qwen3-1.7B's published widths:
+4096-symbol segments, 1024 of them per decode call.
+
+The jit decoders, the jit fused impl and a full-width cached decode block
+must compile.  The four Pallas decode kernels do not lower in Mosaic today
+(its gather lowering refuses their per-lane LUT reads); each refusal is a
+strict xfail, so a kernel that starts to lower fails here until
+``decode_backends.PALLAS_DECODE_LOWERS`` is flipped with it.
+
+The topology is described inside a fixture, never at import time: only one
+process may load the TPU library, and every test worker imports this file.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+SEG = 4096          # symbols per segment (the compressed-resident spec)
+LANES = 1024        # segments per decode call (chip_smoke's chunk budget)
+WIDTH = 4096        # bytes per stream after pow2 bucketing
+LUT = 4096          # 2**12 entries: max code length / tANS table_log 12
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler can be described here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of these compiles
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _streams(sharding, lanes=LANES):
+    return (_sds(sharding, (lanes, WIDTH), jnp.uint8),
+            _sds(sharding, (lanes,), jnp.int32))
+
+
+def _tables(sharding, n):
+    return tuple(_sds(sharding, (LUT,), jnp.int32) for _ in range(n))
+
+
+def _fused_qt(sharding, family, impl, K=2048, N=2048):
+    from repro.kernels.fused_decode_matmul import FusedQT
+    mat = _sds(sharding, (K * N // SEG, WIDTH), jnp.uint8)
+    scale = _sds(sharding, (1, 1), jnp.float32)
+    return FusedQT(mat, _tables(sharding, 2 if family == "prefix" else 3),
+                   scale, scale, family=family, tbits=12, seg=SEG, K=K, N=N,
+                   bits=8, impl=impl)
+
+
+def test_jit_prefix_decoder_compiles(one_chip):
+    from repro.core.decode_jax import decode_streams_jax
+    c = jax.jit(decode_streams_jax, static_argnames=("max_len", "max_count")
+                ).lower(*_streams(one_chip), *_tables(one_chip, 2),
+                        max_len=12, max_count=SEG).compile()
+    assert c.memory_analysis().output_size_in_bytes == LANES * SEG * 4
+
+
+def test_jit_tans_decoder_compiles(one_chip):
+    from repro.core.decode_jax import decode_streams_tans_jax
+    c = jax.jit(decode_streams_tans_jax,
+                static_argnames=("table_log", "max_count")
+                ).lower(*_streams(one_chip), *_tables(one_chip, 3),
+                        table_log=12, max_count=SEG).compile()
+    assert c.memory_analysis().output_size_in_bytes == LANES * SEG * 4
+
+
+def test_jit_fused_impl_compiles(one_chip):
+    from repro.kernels.fused_decode_matmul import fused_decode_matmul
+    x = _sds(one_chip, (4, 1, 2048), jnp.bfloat16)
+    c = jax.jit(fused_decode_matmul).lower(
+        x, _fused_qt(one_chip, "prefix", "jax")).compile()
+    assert c.memory_analysis().output_size_in_bytes == 4 * 2048 * 2
+
+
+def test_qwen3_cached_decode_block_compiles(one_chip):
+    """One full-width Qwen3-1.7B layer against the slot cache, QT weights
+    resident (compressed residency's per-layer block)."""
+    from repro.configs import registry
+    from repro.models import api, dense
+    from repro.models.layers import QT
+    cfg = registry.published(registry.get("qwen3-1.7b"), 4)
+    B, T = 8, 176                         # chip_smoke phase B's slot cache
+    shapes = jax.eval_shape(functools.partial(api.build(cfg).init, cfg),
+                            jax.random.PRNGKey(0))
+    lp = {}
+    for name, s in shapes.items():
+        if not name.startswith("layers/"):
+            continue
+        short, sh = name.split("/", 1)[1], s.shape[1:]
+        if len(sh) == 2:                  # matrices stay 8-bit symbols
+            f32 = _sds(one_chip, (1, 1), jnp.float32)
+            lp[short] = QT(_sds(one_chip, sh, jnp.uint8), f32, f32)
+        else:
+            lp[short] = _sds(one_chip, sh, s.dtype)
+    cache = jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype),
+                         jax.eval_shape(lambda: dense.init_cache(cfg, B, T)))
+    assert lp["wq"].q.shape == (2048, 2048) and lp["w_up"].q.shape == (
+        2048, 6144)
+    c = jax.jit(functools.partial(dense.resident_block, cfg)).lower(
+        lp, _sds(one_chip, (B, 1, cfg.d_model), jnp.bfloat16), cache,
+        _sds(one_chip, (), jnp.int32), _sds(one_chip, (B,), jnp.int32)
+    ).compile()
+    assert c.memory_analysis().temp_size_in_bytes > 0
+
+
+# Mosaic's gather lowering refuses the per-lane LUT reads: an AssertionError
+# for the prefix kernels, "Only 2D gather is supported" for the tANS ones.
+_REFUSED = pytest.mark.xfail(strict=True,
+                             raises=(AssertionError, NotImplementedError),
+                             reason="Mosaic cannot lower per-lane LUT gathers")
+
+
+@_REFUSED
+def test_pallas_huffman_decode_lowers(one_chip):
+    from repro.kernels.huffman_decode import decode_streams_pallas
+    jax.jit(decode_streams_pallas,
+            static_argnames=("max_len", "max_count", "interpret")).lower(
+        *_streams(one_chip, 128), *_tables(one_chip, 2), max_len=12,
+        max_count=SEG).compile()
+
+
+@_REFUSED
+def test_pallas_tans_decode_lowers(one_chip):
+    from repro.kernels.ans_decode import decode_streams_tans_pallas
+    jax.jit(decode_streams_tans_pallas,
+            static_argnames=("table_log", "max_count", "interpret")).lower(
+        *_streams(one_chip, 128), *_tables(one_chip, 3), table_log=12,
+        max_count=SEG).compile()
+
+
+@_REFUSED
+@pytest.mark.parametrize("family", ["prefix", "tans"])
+def test_pallas_fused_kernel_lowers(one_chip, family):
+    from repro.kernels.fused_decode_matmul import fused_decode_matmul
+    x = _sds(one_chip, (8, 2048), jnp.bfloat16)
+    jax.jit(fused_decode_matmul).lower(
+        x, _fused_qt(one_chip, family, "pallas")).compile()
+
