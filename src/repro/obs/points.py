@@ -85,7 +85,11 @@ EXPECTED_POINTS: Dict[str, Dict[str, List[str]]] = {
         "spans": [
             "serve.step",
             "serve.admit_chunk",
-            "serve.decode_batch",
+            "serve.admit",
+            "serve.first_token",
+            "serve.decode_dispatch",
+            "serve.decode_sync",
+            "serve.commit",
         ],
         "metrics": [
             "queue.depth",
@@ -108,7 +112,11 @@ EXPECTED_POINTS: Dict[str, Dict[str, List[str]]] = {
             "fleet.dispatch",
             "serve.step",
             "serve.admit_chunk",
-            "serve.decode_batch",
+            "serve.admit",
+            "serve.first_token",
+            "serve.decode_dispatch",
+            "serve.decode_sync",
+            "serve.commit",
         ],
         "metrics": [
             "fleet.submitted",
@@ -133,7 +141,11 @@ EXPECTED_POINTS: Dict[str, Dict[str, List[str]]] = {
         "spans": [
             "serve.step",
             "serve.admit_chunk",
-            "serve.decode_batch",
+            "serve.admit",
+            "serve.first_token",
+            "serve.decode_dispatch",
+            "serve.decode_sync",
+            "serve.commit",
             "kv.admit",
         ],
         "metrics": [

@@ -36,6 +36,13 @@ flag) is the opt-in: instrumented call sites consult it and fence
 (``jax.block_until_ready``) their outputs so span durations reflect real
 device time — at the cost of serializing the very overlap being measured,
 which is why it defaults off.
+
+Profiler mode (``enable(profiler=True)``) puts the spans on the device
+trace's clock: each span also opens a ``jax.profiler.TraceAnnotation`` of
+the same name, so a ``jax.profiler`` session records it on its host plane
+beside the device's operations, and an idle gap on the device can be read
+against what the host was doing.  The in-memory record is kept as well.
+JAX is imported only there, so the module itself stays stdlib-only.
 """
 from __future__ import annotations
 
@@ -72,7 +79,8 @@ class _SpanCM:
     """Context manager for one span; grabs its id/parent at ``__enter__`` so
     the tree survives children finishing before (or after) their parent."""
 
-    __slots__ = ("tracer", "name", "cat", "args", "id", "parent", "t0")
+    __slots__ = ("tracer", "name", "cat", "args", "id", "parent", "t0",
+                 "annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Dict[str, Any]):
@@ -87,12 +95,18 @@ class _SpanCM:
         stack = tr._stack()
         self.parent = stack[-1] if stack else None
         stack.append(self.id)
+        self.annotation = None
+        if tr._annotation is not None:
+            self.annotation = tr._annotation(self.name)
+            self.annotation.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter_ns()
         tr = self.tracer
+        if self.annotation is not None:
+            self.annotation.__exit__(*exc)
         stack = tr._stack()
         if stack and stack[-1] == self.id:
             stack.pop()
@@ -124,11 +138,17 @@ class Tracer:
 
     ``sync`` is advisory: the tracer never touches device state itself, but
     instrumented call sites fence their jitted outputs when it is set (see
-    module docstring).
+    module docstring).  ``profiler`` holds a
+    ``jax.profiler.TraceAnnotation`` of each span's name open for the span's
+    duration (profiler mode; JAX is imported here, only then).
     """
 
-    def __init__(self, *, sync: bool = False):
+    def __init__(self, *, sync: bool = False, profiler: bool = False):
         self.sync = sync
+        self._annotation = None
+        if profiler:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
         self._epoch = time.perf_counter_ns()
         self._lock = threading.Lock()
         self._events: List[SpanRecord] = []
@@ -263,11 +283,14 @@ _active: Optional[Tracer] = None
 _active_lock = threading.Lock()
 
 
-def enable(*, sync: bool = False) -> Tracer:
-    """Install (and return) a fresh process-global tracer."""
+def enable(*, sync: bool = False, profiler: bool = False) -> Tracer:
+    """Install (and return) a fresh process-global tracer.  ``profiler``
+    also records every span as a ``jax.profiler.TraceAnnotation`` of the
+    same name, on the clock of a ``jax.profiler`` trace (module
+    docstring)."""
     global _active
     with _active_lock:
-        _active = Tracer(sync=sync)
+        _active = Tracer(sync=sync, profiler=profiler)
         return _active
 
 

@@ -215,29 +215,32 @@ class ContinuousEngine:
     # ------------------------------------------------------------ admission
     def _start_prefill(self, req: Request) -> None:
         """Reserve a slot and set up the chunked-prefill pipeline state."""
-        req.state = RequestState.PREFILLING
-        req.t_admitted = time.monotonic()
-        obs_metrics.histogram("queue.wait_s").observe(
-            req.queue_wait_s or 0.0, outcome="admitted")
-        P, chunk = req.prompt_len, self.prefill_chunk
-        padded = -(-P // chunk) * chunk
-        toks = np.zeros((1, padded), np.int32)
-        toks[0, :P] = req.prompt
-        if self.paged:
-            # block admission: claim table entries (consuming shared prefix
-            # blocks) and start prefill AFTER the shared region — the block
-            # manager guarantees the final chunk (position P-1) always runs
-            got = self.slots.alloc(req)
-            assert got is not None, "admission past can_admit"
-            slot, skip = got
-            self._prefilling = dict(req=req, slot=slot, toks=toks, c0=skip,
-                                    last=None, scratch=None)
-            return
-        slot = self.slots.alloc(req)
-        assert slot is not None, "admission with no free slot"
-        self._prefilling = dict(
-            req=req, slot=slot, toks=toks, c0=0, last=None,
-            scratch=self.steps.mod.init_cache(self.cfg, 1, self.sc.max_len))
+        with obs_trace.span("serve.admit", rid=req.rid):
+            req.state = RequestState.PREFILLING
+            req.t_admitted = time.monotonic()
+            obs_metrics.histogram("queue.wait_s").observe(
+                req.queue_wait_s or 0.0, outcome="admitted")
+            P, chunk = req.prompt_len, self.prefill_chunk
+            padded = -(-P // chunk) * chunk
+            toks = np.zeros((1, padded), np.int32)
+            toks[0, :P] = req.prompt
+            if self.paged:
+                # block admission: claim table entries (consuming shared
+                # prefix blocks) and start prefill AFTER the shared region —
+                # the block manager guarantees the final chunk (position
+                # P-1) always runs
+                got = self.slots.alloc(req)
+                assert got is not None, "admission past can_admit"
+                slot, skip = got
+                self._prefilling = dict(req=req, slot=slot, toks=toks,
+                                        c0=skip, last=None, scratch=None)
+                return
+            slot = self.slots.alloc(req)
+            assert slot is not None, "admission with no free slot"
+            self._prefilling = dict(
+                req=req, slot=slot, toks=toks, c0=0, last=None,
+                scratch=self.steps.mod.init_cache(self.cfg, 1,
+                                                  self.sc.max_len))
 
     def _advance_prefill(self) -> None:
         """Run ONE prefill chunk; on the last chunk, splice the scratch rows
@@ -263,21 +266,22 @@ class ContinuousEngine:
             return
         self._prefilling = None
         slot = st["slot"]
-        if self.paged:
-            self.slots.insert(slot, P)      # prefill wrote the pool in place
-        else:
-            self.slots.insert(slot, st["scratch"], P)
-        key, sub = jax.random.split(jax.random.PRNGKey(req.sampling.seed))
-        tok = int(sample(st["last"], sub, req.sampling.temperature,
-                         self.cfg.vocab)[0])
-        req.t_first_token = time.monotonic()
-        obs_metrics.histogram("request.ttft_s").observe(req.ttft_s or 0.0)
-        req.state = RequestState.DECODING
-        req.output.append(tok)
-        self._tokens[slot] = tok
-        kd = key if key.dtype == jnp.uint32 else jax.random.key_data(key)
-        self._keys[slot] = np.asarray(kd, np.uint32)
-        self._temps[slot] = req.sampling.temperature
+        with obs_trace.span("serve.first_token", rid=req.rid):
+            if self.paged:
+                self.slots.insert(slot, P)  # prefill wrote the pool in place
+            else:
+                self.slots.insert(slot, st["scratch"], P)
+            key, sub = jax.random.split(jax.random.PRNGKey(req.sampling.seed))
+            tok = int(sample(st["last"], sub, req.sampling.temperature,
+                             self.cfg.vocab)[0])
+            req.t_first_token = time.monotonic()
+            obs_metrics.histogram("request.ttft_s").observe(req.ttft_s or 0.0)
+            req.state = RequestState.DECODING
+            req.output.append(tok)
+            self._tokens[slot] = tok
+            kd = key if key.dtype == jnp.uint32 else jax.random.key_data(key)
+            self._keys[slot] = np.asarray(kd, np.uint32)
+            self._temps[slot] = req.sampling.temperature
         if self._hit_stop(req, tok):
             self._detach(slot, req, tok)
         elif self.handoff_sink is not None:
@@ -327,7 +331,7 @@ class ContinuousEngine:
         if not active:
             return progressed
 
-        with obs_trace.span("serve.decode_batch", active=len(active)):
+        with obs_trace.span("serve.decode_dispatch", active=len(active)):
             pos = jnp.asarray(self.slots.kv_len)
             tok = jnp.asarray(self._tokens[:, None])
             if self.paged:
@@ -340,18 +344,20 @@ class ContinuousEngine:
             new_tok, new_keys = _sample_slots(logits, jnp.asarray(self._keys),
                                               jnp.asarray(self._temps),
                                               self.cfg.vocab)
+        with obs_trace.span("serve.decode_sync"):
             new_tok = np.asarray(new_tok)
-        self._keys = np.array(new_keys)     # copy: host mirror stays writable
-        self.n_decode_steps += 1
-        obs_metrics.counter("serve.tokens").inc(len(active))
-        for s in active:
-            self.slots.kv_len[s] += 1
-            req = self.slots.requests[s]
-            t = int(new_tok[s])
-            req.output.append(t)
-            self._tokens[s] = t
-            if self._hit_stop(req, t):
-                self._detach(s, req, t)
+            self._keys = np.array(new_keys)  # copy: host mirror stays writable
+        with obs_trace.span("serve.commit"):
+            self.n_decode_steps += 1
+            obs_metrics.counter("serve.tokens").inc(len(active))
+            for s in active:
+                self.slots.kv_len[s] += 1
+                req = self.slots.requests[s]
+                t = int(new_tok[s])
+                req.output.append(t)
+                self._tokens[s] = t
+                if self._hit_stop(req, t):
+                    self._detach(s, req, t)
         return True
 
     def run(self) -> List[Request]:

@@ -351,3 +351,137 @@ def test_bit_identity_trace_on_vs_off():
     # and the registry recorded the serve without being asked
     assert metrics.histogram("serve.decode_step_s").count() > 0
     assert metrics.counter("serve.tokens").total() > 0
+
+
+# ---------------------------------------------------------------------------
+# ContinuousEngine.step's spans: profiler mode, host syncs, pure observation
+
+STEP_CHILDREN = ("serve.admit", "serve.admit_chunk", "serve.first_token",
+                 "serve.decode_dispatch", "serve.decode_sync", "serve.commit")
+
+
+@pytest.fixture(scope="module")
+def tiny_continuous():
+    """A reduced dense model and one ``ServeSteps`` shared by every engine
+    of the module, so its programs compile once."""
+    import jax
+    from repro.configs import registry
+    from repro.models import api
+    from repro.serving import engine as serving_engine
+
+    cfg = registry.reduced(registry.get("qwen3-1.7b"))
+    params = api.build(cfg).init(cfg, jax.random.PRNGKey(0))
+    sc = serving_engine.ServeConfig(max_len=32)
+    return cfg, params, sc, serving_engine.ServeSteps(cfg, sc)
+
+
+def _serve_continuous(tiny, lens=(5, 11, 19), new=(4, 3, 5)):
+    """Three greedy requests through two slots (one queues), prefill in
+    8-token chunks, one chunk a step; returns the engine and the outputs."""
+    from repro.serving.batching import ContinuousEngine
+    cfg, params, sc, steps = tiny
+    ce = ContinuousEngine(cfg, params, sc, n_slots=2, prefill_chunk=8,
+                          admit_chunks_per_step=1, steps=steps)
+    rng = np.random.default_rng(0)
+    reqs = [ce.submit(rng.integers(0, cfg.vocab, (n,)).astype(np.int32), m)
+            for n, m in zip(lens, new)]
+    ce.run()
+    return ce, [list(r.output) for r in reqs]
+
+
+def test_profiler_mode_puts_step_spans_on_the_profilers_host_plane(
+        tiny_continuous, tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    _serve_continuous(tiny_continuous)          # compile outside the trace
+    jax.profiler.start_trace(os.fspath(tmp_path))
+    tr = trace.enable(profiler=True)
+    try:
+        ce, _ = _serve_continuous(tiny_continuous)
+    finally:
+        trace.disable()
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.fspath(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    by_name = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("serve."):
+                    by_name.setdefault(e.name, []).append(
+                        (e.start_ns, e.end_ns))
+    assert set(STEP_CHILDREN) | {"serve.step"} <= set(by_name)
+    steps = by_name["serve.step"]
+    for name in STEP_CHILDREN:
+        for a, b in by_name[name]:
+            assert any(s <= a and b <= e for s, e in steps), name
+    assert len(by_name["serve.decode_sync"]) == ce.n_decode_steps
+    assert len(by_name["serve.admit"]) == len(by_name["serve.first_token"]) \
+        == 3
+    # the in-memory record is kept alongside: same spans, parents, args
+    recs = tr.events
+    step_ids = {e.id for e in recs if e.name == "serve.step"}
+    assert len(step_ids) == len(steps)
+    for e in recs:
+        if e.name in STEP_CHILDREN:
+            assert e.parent in step_ids, e.name
+    assert sorted(e.args["rid"] for e in recs if e.name == "serve.admit") \
+        == sorted(e.args["rid"] for e in recs
+                  if e.name == "serve.first_token")
+
+
+def test_step_spans_count_decode_steps_and_admissions(tiny_continuous):
+    """The spans alone count the step's blocking reads: one
+    ``serve.decode_sync`` a decode step, one ``serve.first_token`` an
+    admission; ``active`` sums to the tokens the decode steps made."""
+    tr = trace.enable()
+    try:
+        ce, outs = _serve_continuous(tiny_continuous)
+    finally:
+        trace.disable()
+    assert [len(o) for o in outs] == [4, 3, 5]
+    by_name = {}
+    for e in tr.events:
+        by_name.setdefault(e.name, []).append(e)
+    for name in ("serve.decode_dispatch", "serve.decode_sync",
+                 "serve.commit"):
+        assert len(by_name[name]) == ce.n_decode_steps, name
+    assert len(by_name["serve.admit"]) == \
+        len(by_name["serve.first_token"]) == 3
+    assert sum(e.args["active"] for e in by_name["serve.decode_dispatch"]) \
+        == sum(map(len, outs)) - 3
+    assert {e.args["rid"] for e in by_name["serve.admit_chunk"]} == \
+        {e.args["rid"] for e in by_name["serve.admit"]}
+
+
+def test_continuous_bit_identity_trace_off_vs_profiler_mode(tiny_continuous):
+    _, off = _serve_continuous(tiny_continuous)
+    tr = trace.enable(profiler=True)
+    _, on = _serve_continuous(tiny_continuous)
+    trace.disable()
+    assert on == off
+    assert any(e.name == "serve.decode_sync" for e in tr.events)
+
+
+def test_disabled_tracing_builds_no_trace_annotation(tiny_continuous,
+                                                     monkeypatch):
+    import jax
+
+    class Refused:
+        def __init__(self, *a, **k):
+            raise AssertionError("TraceAnnotation built")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Refused)
+    assert trace.span("serve.step") is trace.span("serve.commit")
+    _serve_continuous(tiny_continuous)          # tracing off
+    trace.enable()                              # on, not in profiler mode
+    _serve_continuous(tiny_continuous)
+    trace.enable(profiler=True)                 # the patch does bite here
+    with pytest.raises(AssertionError, match="TraceAnnotation built"):
+        with trace.span("serve.step"):
+            pass
