@@ -3,15 +3,28 @@
 Models are mesh-agnostic; the launch layer installs hints so memory-critical
 *activation* tensors (attention q/k/v and scores at 32k+) receive explicit
 ``with_sharding_constraint``s instead of relying on GSPMD propagation alone.
-Attention uses ONE merged head axis (see ``layers.gqa_attention``), so every
-constraint here is expressible as a plain PartitionSpec:
+Attention (``layers.gqa_attention``) takes one of two forms, chosen from
+the hints by :func:`kv_heads_cover_model`:
 
-* q/k/v (B, T, H, hd): batch axes on B, model axis on H; for decode the
-  KV-time dim T instead carries the cache's sequence sharding
-  (``kv_seq_axes`` — "model" when the arch's KV head count cannot cover the
-  model axis, the data axes for single-request long-context).
-* scores (B, H, Sq, T): batch on B, model on H when free, cache sharding on T
-  (GSPMD emits the partial-softmax psum — flash-decoding's combine).
+* grouped — q viewed as (B, S, KV, G, hd) against K/V as stored,
+  (B, T, KV, hd): taken with no hints, under exact serving, or when the
+  model axis divides the KV head count.  The KV axis carries the model
+  sharding.
+* replicated — K/V broadcast up to all H query heads (G = 1): taken only
+  where the model axis is wider than the KV heads can cover, so that one
+  merged H axis shards over it.
+
+Either way every constraint is a plain PartitionSpec over the head axis
+that attention keeps (KV or H):
+
+* q (B, S, heads, [G,] hd) and k/v (B, T, heads, hd): batch axes on B,
+  model axis on the heads; for decode the KV-time dim T instead carries the
+  cache's sequence sharding (``kv_seq_axes`` — "model" when the arch's KV
+  head count cannot cover the model axis, the data axes for single-request
+  long-context).
+* scores (B, heads, [G,] Sq, T): batch on B, model on the heads when free,
+  cache sharding on T (GSPMD emits the partial-softmax psum —
+  flash-decoding's combine).
 
 Install with :func:`set_hints` before tracing; smoke tests leave it unset and
 models run constraint-free on one device.
@@ -80,37 +93,52 @@ def constrain_replicated(x: jax.Array) -> jax.Array:
         x, NamedSharding(h.mesh, P(*([None] * x.ndim))))
 
 
+def kv_heads_cover_model(n_kv: int) -> bool:
+    """Whether attention may keep K/V at their own ``n_kv`` heads (the
+    grouped form): no hints, exact serving, no model axis, or a model axis
+    that divides ``n_kv``.  False only where the model axis is wider than
+    the KV heads can cover, so K/V must be replicated up to every query
+    head for the head axis to shard."""
+    h = get_hints()
+    if h is None or h.exact_tp or not h.model_axis:
+        return True
+    return _fits(n_kv, h.mesh, (h.model_axis,))
+
+
 def constrain_heads(x: jax.Array, *, is_cache_side: bool = False) -> jax.Array:
-    """Constrain (B, T, H, hd): batch/B, model/H, cache sharding on T."""
+    """Constrain (B, T, heads, ...): batch/B, model/heads, cache sharding on
+    T; trailing dims (the query group, hd) stay unsharded."""
     h = get_hints()
     if h is None or h.exact_tp:     # exact serving: no activation constraints
         return x
-    B, T, H, _ = x.shape
+    B, T, N = x.shape[:3]
     batch = h.batch_axes if _fits(B, h.mesh, h.batch_axes) else None
     seq = h.kv_seq_axes if (is_cache_side
                             and _fits(T, h.mesh, h.kv_seq_axes)) else None
     heads = None
     m = h.model_axis
-    if m and _fits(H, h.mesh, (m,)) and (seq is None or m not in seq):
+    if m and _fits(N, h.mesh, (m,)) and (seq is None or m not in seq):
         heads = m
     return jax.lax.with_sharding_constraint(
-        x, NamedSharding(h.mesh, P(batch, seq, heads, None)))
+        x, NamedSharding(h.mesh, P(batch, seq, heads,
+                                   *([None] * (x.ndim - 3)))))
 
 
 def constrain_scores(s: jax.Array) -> jax.Array:
-    """Constrain (B, H, Sq, T) attention scores."""
+    """Constrain (B, heads, ..., Sq, T) attention scores."""
     h = get_hints()
     if h is None or h.exact_tp:     # exact serving: no activation constraints
         return s
-    B, H, Sq, T = s.shape
+    B, N, T = s.shape[0], s.shape[1], s.shape[-1]
     batch = h.batch_axes if _fits(B, h.mesh, h.batch_axes) else None
     seq = h.kv_seq_axes if _fits(T, h.mesh, h.kv_seq_axes) else None
     heads = None
     m = h.model_axis
-    if m and _fits(H, h.mesh, (m,)) and (seq is None or m not in seq):
+    if m and _fits(N, h.mesh, (m,)) and (seq is None or m not in seq):
         heads = m
     return jax.lax.with_sharding_constraint(
-        s, NamedSharding(h.mesh, P(batch, heads, None, seq)))
+        s, NamedSharding(h.mesh, P(batch, heads, *([None] * (s.ndim - 3)),
+                                   seq)))
 
 
 def constrain_activation(x: jax.Array) -> jax.Array:

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.fused_decode_matmul import FusedQT, fused_decode_matmul
+from repro.obs import metrics as obs_metrics
 
 # --------------------------------------------------------------------------- schema
 
@@ -269,40 +270,52 @@ def gqa_attention(
     serving mode).  Per-slot offsets disable q-block chunking (the block scan
     would need ragged bases); callers pass ``q_block=0`` on that path.
 
-    SPMD formulation: KV heads are broadcast up to the full head count BEFORE
-    the score einsum (MaxText-style "KV replication"), so every attention
-    tensor carries one merged head axis H that shards cleanly over the model
-    axis — the (KV, G) split axes that GSPMD must otherwise co-shard are never
-    materialized.  The broadcast is sharded by the head constraint, so each
-    chip only materializes its own H/|model| head slice.
+    Head grouping: ``q`` is viewed as ``(B, S, KV, G, hd)`` and the scores
+    and weighted values are contracted against K/V exactly as stored,
+    ``(B, T, KV, hd)``, so no tensor with H heads over the T cache rows is
+    ever built (decode reads the cache once per KV head).  That grouped form
+    is taken whenever the installed sharding hints allow it
+    (:func:`repro.distributed.ctx.kv_heads_cover_model`): no hints, exact
+    serving, or a model axis that divides KV — the KV axis then carries the
+    model sharding.  Only where the model axis is wider than the KV heads
+    can cover are K/V broadcast up to all H heads first (MaxText-style "KV
+    replication", G = 1 per head), so that the merged H axis shards over the
+    model axis and each chip materializes only its own H/|model| slice.  The
+    counter ``attn.form`` (label ``form=grouped|replicated``) counts each
+    trace's choice.
 
     Chunking over the query axis bounds the live (Qb x T) score tensor — the
     memory-realistic lowering used by the dry-run for long-sequence prefill
     (the softmax over T is exact per block; no online accumulation needed).
     """
-    from repro.distributed.ctx import constrain_heads, constrain_scores
+    from repro.distributed.ctx import (constrain_heads, constrain_scores,
+                                       kv_heads_cover_model)
     B, S, H, hd = q.shape
     T, KV = k.shape[1], k.shape[2]
     G = H // KV
     scale = hd ** -0.5
-    q = constrain_heads(q)
-    if G > 1:
+    replicate = G > 1 and not kv_heads_cover_model(KV)
+    obs_metrics.counter("attn.form").inc(
+        form="replicated" if replicate else "grouped")
+    if replicate:
         k = jnp.broadcast_to(k[:, :, :, None, :], (B, T, KV, G, hd)
                              ).reshape(B, T, H, hd)
         v = jnp.broadcast_to(v[:, :, :, None, :], (B, T, KV, G, hd)
                              ).reshape(B, T, H, hd)
+        KV, G = H, 1
+    q = constrain_heads(q.reshape(B, S, KV, G, hd))
     k = constrain_heads(k, is_cache_side=True)
     v = constrain_heads(v, is_cache_side=True)
 
     def block(qb: jax.Array, qpos: jax.Array) -> jax.Array:
-        # qb: (B, Sb, H, hd); qpos: (Sb,) or (B, Sb) global positions
+        # qb: (B, Sb, KV, G, hd); qpos: (Sb,) or (B, Sb) global positions
         # bf16 operands + f32 accumulation (MXU-style): keeps the KV-cache
         # read at 2 bytes/element — an f32 cast before the dot doubles the
         # cache wire/HBM traffic (EXPERIMENTS.md §Perf H1 iteration 2)
-        s = jnp.einsum("bsnh,btnh->bnst", (qb * scale).astype(jnp.bfloat16),
+        s = jnp.einsum("bskgh,btkh->bkgst", (qb * scale).astype(jnp.bfloat16),
                        k.astype(jnp.bfloat16),
                        preferred_element_type=jnp.float32)
-        s = constrain_scores(s)                       # (B, H, Sq, T)
+        s = constrain_scores(s)                       # (B, KV, G, Sq, T)
         tpos = jnp.arange(T)
         mask = jnp.ones(qpos.shape + (T,), bool)      # (Sb, T) or (B, Sb, T)
         if causal:
@@ -316,9 +329,10 @@ def gqa_attention(
                 mask &= tpos < kl
         while mask.ndim < 3:                          # -> (B|1, Sb, T)
             mask = mask[None]
-        s = jnp.where(mask[:, None], s, NEG_INF)      # (B|1, 1, Sq, T)
+        s = jnp.where(mask[:, None, None], s, NEG_INF)  # (B|1, 1, 1, Sq, T)
         p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("bnst,btnh->bsnh", p.astype(v.dtype), v)
+        o = jnp.einsum("bkgst,btkh->bskgh", p.astype(v.dtype), v)
+        return o.reshape(qb.shape[:2] + (H, hd))
 
     qpos0 = jnp.asarray(q_offset)[..., None] + jnp.arange(S)  # (S,) or (B, S)
     if q_block <= 0 or q_block >= S or qpos0.ndim > 1:
@@ -326,7 +340,7 @@ def gqa_attention(
 
     assert S % q_block == 0, (S, q_block)
     nb = S // q_block
-    qb = q.reshape(B, nb, q_block, H, hd)
+    qb = q.reshape(B, nb, q_block, KV, G, hd)
 
     def body(_, qi):
         qblk, base = qi

@@ -177,6 +177,7 @@ INFORMATIONAL_POINTS: Dict[str, List[str]] = {
         "resident.prefetch_issue",
     ],
     "metrics": [
+        "attn.form",                # trace-time: one per traced attention
         "decode.calls",             # scheduler chunking detail
         "fleet.admission_rejects",  # admission-gate vetoes (chaos/test seam)
         "fleet.handoff_bytes",      # disaggregated fleets only

@@ -4,8 +4,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs import registry
+from repro.distributed import ctx
+from repro.launch import mesh as mesh_lib
+from repro.models import dense
+from repro.models.dense import dequantize_kv, quantize_kv
 from repro.models.layers import gqa_attention, rms_norm, rope, softmax_xent
 from repro.models.mamba2 import _causal_conv, ssd_chunked, ssd_step
+from repro.obs import metrics as obs_metrics
 
 
 def _naive_attention(q, k, v, causal, kv_len=None):
@@ -23,7 +29,7 @@ def _naive_attention(q, k, v, causal, kv_len=None):
             if causal:
                 mask &= np.tril(np.ones((S, T), bool))
             if kv_len is not None:
-                mask[:, kv_len:] = False
+                mask[:, int(np.broadcast_to(kv_len, (B,))[b]):] = False
             s = np.where(mask, s, -1e9)
             p = np.exp(s - s.max(-1, keepdims=True))
             p /= p.sum(-1, keepdims=True)
@@ -52,6 +58,87 @@ def test_gqa_attention_decode_with_cache_mask():
     v = jnp.asarray(rng.normal(size=(B, T, KV, hd)), jnp.float32)
     out = gqa_attention(q, k, v, causal=False, kv_len=jnp.int32(10))
     want = _naive_attention(q, k, v, causal=False, kv_len=10)
+    np.testing.assert_allclose(np.asarray(out, np.float32), want, atol=5e-2)
+
+
+@pytest.mark.parametrize("cache", ["bf16", "int8"])
+@pytest.mark.parametrize("G", [1, 2, 4, 8])
+def test_gqa_attention_decode_per_slot_matches_naive(G, cache):
+    # one decode step of a slot batch: S = 1, every slot at its own length
+    rng = np.random.default_rng(G)
+    B, T, KV, hd = 3, 24, 2, 16
+    H = KV * G
+    kv_len = jnp.asarray([1, 13, 24], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(B, 1, H, hd)), jnp.bfloat16)
+    k = jnp.asarray(rng.normal(size=(B, T, KV, hd)), jnp.bfloat16)
+    v = jnp.asarray(rng.normal(size=(B, T, KV, hd)), jnp.bfloat16)
+    if cache == "int8":
+        k, v = dequantize_kv(*quantize_kv(k)), dequantize_kv(*quantize_kv(v))
+    out = gqa_attention(q, k, v, causal=False, q_offset=kv_len - 1,
+                        kv_len=kv_len)
+    assert out.shape == (B, 1, H, hd)
+    want = _naive_attention(q, k, v, causal=False, kv_len=np.asarray(kv_len))
+    np.testing.assert_allclose(np.asarray(out, np.float32), want, atol=5e-2)
+
+
+def _walk_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk_eqns(sub)
+
+
+def test_decode_step_never_widens_kv_to_query_heads():
+    cfg = registry.reduced(registry.get("qwen3-1.7b"))
+    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    assert (H, KV) == (4, 2)
+    B, T = 3, 40
+    params = jax.eval_shape(lambda: dense.init(cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: dense.init_cache(cfg, B, T))
+    token = jax.ShapeDtypeStruct((B, 1), jnp.int32)
+    pos = jax.ShapeDtypeStruct((B,), jnp.int32)
+    closed = jax.make_jaxpr(
+        lambda p, t, c, s: dense.decode_step(cfg, p, t, c, s))(
+            params, token, cache, pos)
+    widened = [e.outvars[0].aval.shape for e in _walk_eqns(closed.jaxpr)
+               if e.primitive.name == "broadcast_in_dim"
+               and T in e.outvars[0].aval.shape
+               and np.prod(e.outvars[0].aval.shape) >= B * T * H * hd]
+    assert not widened, widened
+
+
+def _form_count(form):
+    return obs_metrics.counter("attn.form").value(form=form)
+
+
+@pytest.mark.parametrize("KV,form", [(2, "replicated"), (4, "grouped")])
+def test_attn_form_follows_sharding_hints(KV, form):
+    B, S, T, H, hd = 2, 1, 16, 8, 16
+    rng = np.random.default_rng(KV)
+    q = jnp.asarray(rng.normal(size=(B, S, H, hd)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(B, T, KV, hd)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B, T, KV, hd)), jnp.float32)
+
+    def attend(q, k, v):     # a fresh function each time: traced anew
+        return jax.jit(lambda q, k, v: gqa_attention(
+            q, k, v, causal=False, kv_len=jnp.int32(11)))(q, k, v)
+
+    before = _form_count("grouped")
+    attend(q, k, v)
+    assert _form_count("grouped") == before + 1      # no hints: grouped
+
+    # model axis of 4: divides KV = 4, wider than KV = 2 can cover
+    mesh = mesh_lib.make_serve_mesh(2, 4)
+    counts = {f: _form_count(f) for f in ("grouped", "replicated")}
+    ctx.set_hints(ctx.ShardingHints(mesh=mesh, batch_axes=("data",)))
+    try:
+        out = attend(q, k, v)
+    finally:
+        ctx.set_hints(None)
+    assert _form_count(form) == counts[form] + 1
+    other = "grouped" if form == "replicated" else "replicated"
+    assert _form_count(other) == counts[other]
+    want = _naive_attention(q, k, v, causal=False, kv_len=11)
     np.testing.assert_allclose(np.asarray(out, np.float32), want, atol=5e-2)
 
 
