@@ -41,10 +41,35 @@ class SSMConfig:
         return self.d_inner(d_model) // self.head_dim
 
 
+# Rotary forms: the share of each head that rotates, and its pairs
+# ("half": (x[i], x[i + r/2]), rotate-half; "interleaved": (x[2i], x[2i+1])).
+ROTARY: Dict[str, Tuple[float, str]] = {
+    "full": (1.0, "half"),                          # Llama, Qwen3
+    "partial_interleaved": (0.5, "interleaved"),    # GLM-4
+}
+
+# The dense block's attention traits when nothing sets them (Qwen3's,
+# Llama's).
+TRAIT_DEFAULTS: Dict[str, object] = dict(qkv_bias=False, rotary="full",
+                                         norm_eps=1e-6)
+
+# Published blocks the dense family computes with attention traits of their
+# own.  ``ArchConfig(family=<key>, ...)`` resolves here, and only here, to
+# ``family="dense"`` with these traits, so model dispatch, serving and
+# launch code see one dense family.  An explicit value that differs from
+# the family's trait is an error.
+FAMILY_TRAITS: Dict[str, Dict[str, object]] = {
+    # GLM-4 (THUDM/glm-4-9b-chat): bias on Q/K/V only; interleaved rotary
+    # over the first half of each head; RMSNorm epsilon 1.5625e-7
+    "glm4": dict(qkv_bias=True, rotary="partial_interleaved",
+                 norm_eps=1.5625e-7),
+}
+
+
 @dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
-    family: str                     # dense | moe | ssm | hybrid | encdec
+    family: str                     # dense | moe | ssm | hybrid | encdec, or a FAMILY_TRAITS key
     n_layers: int
     d_model: int
     n_heads: int
@@ -62,10 +87,41 @@ class ArchConfig:
     frontend: Optional[str] = None  # 'vq_image' | 'audio' stub note
     subquadratic: bool = False      # eligible for long_500k decode
     source: str = ""
+    # attention traits of the dense block; None takes the family's trait,
+    # else TRAIT_DEFAULTS
+    qkv_bias: Optional[bool] = None     # Q, K and V projections carry a bias
+    rotary: Optional[str] = None        # a ROTARY key
+    norm_eps: Optional[float] = None    # RMSNorm epsilon
+
+    def __post_init__(self):
+        traits = FAMILY_TRAITS.get(self.family, {})
+        for key, default in TRAIT_DEFAULTS.items():
+            value, trait = getattr(self, key), traits.get(key, default)
+            if value is None:
+                object.__setattr__(self, key, trait)
+            elif key in traits and value != trait:
+                raise ValueError(f"{self.name}: family {self.family!r} has "
+                                 f"{key}={trait!r}, not {value!r}")
+        if traits:
+            object.__setattr__(self, "family", "dense")
+        if self.rotary not in ROTARY:
+            raise ValueError(f"{self.name}: rotary {self.rotary!r} is not one "
+                             f"of {sorted(ROTARY)}")
 
     @property
     def hd(self) -> int:
         return self.head_dim if self.head_dim else self.d_model // self.n_heads
+
+    @property
+    def rope_dim(self) -> int:
+        """Rotary width: the leading dims of each head that are rotated;
+        the rest pass through unrotated."""
+        return int(self.hd * ROTARY[self.rotary][0])
+
+    @property
+    def rope_pairs(self) -> str:
+        """``"half"`` or ``"interleaved"`` (``ROTARY``)."""
+        return ROTARY[self.rotary][1]
 
     def padded_vocab(self, multiple: int = 512) -> int:
         return _round_up(self.vocab, multiple)

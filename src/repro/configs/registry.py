@@ -63,6 +63,7 @@ def reduced(cfg: ArchConfig) -> ArchConfig:
         tie_embeddings=cfg.tie_embeddings, attn_period=min(cfg.attn_period, 4),
         enc_layers=min(cfg.enc_layers, 2), frontend=cfg.frontend,
         subquadratic=cfg.subquadratic, source="reduced smoke variant",
+        qkv_bias=cfg.qkv_bias, rotary=cfg.rotary, norm_eps=cfg.norm_eps,
     )
     if cfg.moe:
         kw["moe"] = MoEConfig(

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.obs import metrics as obs_metrics
 from .layers import (QT, Schema, Spec, gather_blocks, gqa_attention,
                      init_params, kv_dequantize, kv_quantize, matmul, rms_norm,
                      rope, scatter_blocks, softmax_xent, swiglu, take_rows,
@@ -38,6 +39,10 @@ def schema(cfg: ArchConfig) -> Schema:
     }
     if not cfg.tie_embeddings:
         s["lm_head"] = Spec((D, Vp), ("embed", "vocab"), 0.02)
+    if cfg.qkv_bias:
+        s["layers/q_bias"] = Spec((L, H * hd), ("layers", "heads"), "zeros", jnp.float32)
+        s["layers/k_bias"] = Spec((L, KV * hd), ("layers", "kv"), "zeros", jnp.float32)
+        s["layers/v_bias"] = Spec((L, KV * hd), ("layers", "kv"), "zeros", jnp.float32)
     if cfg.qk_norm:
         s["layers/q_norm"] = Spec((L, hd), ("layers", None), "ones", jnp.float32)
         s["layers/k_norm"] = Spec((L, hd), ("layers", None), "ones", jnp.float32)
@@ -68,6 +73,34 @@ def dequantize_kv(q: jax.Array, s: jax.Array) -> jax.Array:
     return q.astype(jnp.bfloat16) * s.astype(jnp.bfloat16)
 
 
+def _qkv(cfg: ArchConfig, lp: Dict[str, Any], x: jax.Array, positions):
+    """Q, K and V of the attention sub-block, as every attention path takes
+    them: pre-norm, projection, bias (``cfg.qkv_bias``), per-head qk-norm
+    (``cfg.qk_norm``), and rotary in ``cfg.rotary``'s form.  x: (B, S, D)
+    -> q (B, S, H, hd), k and v (B, S, KV, hd).  The counter ``attn.rope``
+    counts each trace by its rotary form and bias."""
+    B, S, _ = x.shape
+    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+
+    def project(w, b, n):
+        t = matmul(h, lp[w])
+        if cfg.qkv_bias:
+            t = t + lp[b].astype(t.dtype)
+        return t.reshape(B, S, n, hd)
+
+    q, k, v = project("wq", "q_bias", H), project("wk", "k_bias", KV), \
+        project("wv", "v_bias", KV)
+    if cfg.qk_norm:
+        q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+    obs_metrics.counter("attn.rope").inc(rotary=cfg.rotary,
+                                         qkv_bias=str(int(cfg.qkv_bias)))
+    q = rope(q, positions, cfg.rope_theta, cfg.rope_dim, cfg.rope_pairs)
+    k = rope(k, positions, cfg.rope_theta, cfg.rope_dim, cfg.rope_pairs)
+    return q, k, v
+
+
 def _attn(cfg: ArchConfig, lp: Dict[str, Any], x: jax.Array, *, positions,
           cache: Optional[Tuple] = None, pos=None, q_block: int = 0, unroll: int = 1):
     """Attention sub-block; returns (out, new_cache).
@@ -79,16 +112,8 @@ def _attn(cfg: ArchConfig, lp: Dict[str, Any], x: jax.Array, *, positions,
     attended causally against the whole cache (chunked prefill).
     """
     B, S, D = x.shape
-    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    h = rms_norm(x, lp["attn_norm"])
-    q = matmul(h, lp["wq"]).reshape(B, S, H, hd)
-    k = matmul(h, lp["wk"]).reshape(B, S, KV, hd)
-    v = matmul(h, lp["wv"]).reshape(B, S, KV, hd)
-    if cfg.qk_norm:
-        q = rms_norm(q, lp["q_norm"])
-        k = rms_norm(k, lp["k_norm"])
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    H, hd = cfg.n_heads, cfg.hd
+    q, k, v = _qkv(cfg, lp, x, positions)
     if cache is None:
         attn = gqa_attention(q, k, v, causal=True, q_block=q_block, unroll=unroll)
         new_cache = (k, v)
@@ -114,7 +139,7 @@ def _block(cfg: ArchConfig, lp, x, *, positions, cache=None, pos=None,
     attn_out, new_cache = _attn(cfg, lp, x, positions=positions, cache=cache, pos=pos,
                                 q_block=q_block, unroll=unroll)
     x = x + attn_out
-    h = rms_norm(x, lp["mlp_norm"])
+    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     x = x + swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
     return x, new_cache
 
@@ -134,7 +159,7 @@ def forward(cfg: ArchConfig, params, tokens, *, unroll: int = 1, q_block: int = 
 
     fn = jax.checkpoint(body) if remat else body
     x, caches = jax.lax.scan(fn, x, stack, unroll=unroll)
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return x, caches
 
 
@@ -222,7 +247,7 @@ def decode_step(cfg: ArchConfig, params, token, cache, pos, *, unroll: int = 1):
     keys = ("k", "v", "k_scale", "v_scale") if q8 else ("k", "v")
     x, out = jax.lax.scan(body, x, (stack, *[cache[k] for k in keys]),
                           unroll=unroll)
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return logits_fn(cfg, params, x), dict(zip(keys, out))
 
 
@@ -249,7 +274,7 @@ def embed_step(cfg: ArchConfig, params, tokens):
 def head_step(cfg: ArchConfig, params, x, *, last_only: bool = False):
     """Final norm + logits (the post-loop lines of the step functions).
     ``last_only`` reproduces `prefill`'s last-position slice."""
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if last_only:
         x = x[:, -1:, :]
     return logits_fn(cfg, params, x)
@@ -325,7 +350,7 @@ def prefill_chunk(cfg: ArchConfig, params, tokens, cache, pos, *,
     keys = ("k", "v", "k_scale", "v_scale") if q8 else ("k", "v")
     x, out = jax.lax.scan(body, x, (stack, *[cache[k] for k in keys]),
                           unroll=unroll)
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return logits_fn(cfg, params, x), dict(zip(keys, out))
 
 
@@ -390,17 +415,9 @@ def _paged_attn(cfg: ArchConfig, lp: Dict[str, Any], x: jax.Array, *,
     scatter and dequantize the gathered sequence in-graph.
     """
     B, S, D = x.shape
-    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    H, hd = cfg.n_heads, cfg.hd
     positions = jnp.asarray(pos)[:, None] + jnp.arange(S)     # (B, S)
-    h = rms_norm(x, lp["attn_norm"])
-    q = matmul(h, lp["wq"]).reshape(B, S, H, hd)
-    k = matmul(h, lp["wk"]).reshape(B, S, KV, hd)
-    v = matmul(h, lp["wv"]).reshape(B, S, KV, hd)
-    if cfg.qk_norm:
-        q = rms_norm(q, lp["q_norm"])
-        k = rms_norm(k, lp["k_norm"])
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    q, k, v = _qkv(cfg, lp, x, positions)
     _, bits = _pool_meta(cfg, pc)
     if bits == 16:
         new = {"k": scatter_blocks(pc["k"], bt, positions, k),
@@ -431,7 +448,7 @@ def _paged_attn(cfg: ArchConfig, lp: Dict[str, Any], x: jax.Array, *,
 def _paged_block(cfg: ArchConfig, lp, x, *, pc, bt, pos):
     attn_out, new = _paged_attn(cfg, lp, x, pc=pc, bt=bt, pos=pos)
     x = x + attn_out
-    h = rms_norm(x, lp["mlp_norm"])
+    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     x = x + swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
     return x, new
 
@@ -456,7 +473,7 @@ def paged_decode_step(cfg: ArchConfig, params, token, pool, bt, pos, *,
 
     x, out = jax.lax.scan(body, x, (stack, *[pool[k] for k in keys]),
                           unroll=unroll)
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return logits_fn(cfg, params, x), dict(zip(keys, out))
 
 
@@ -482,5 +499,5 @@ def paged_prefill_chunk(cfg: ArchConfig, params, tokens, pool, bt, pos, *,
 
     x, out = jax.lax.scan(body, x, (stack, *[pool[k] for k in keys]),
                           unroll=unroll)
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return logits_fn(cfg, params, x), dict(zip(keys, out))

@@ -224,19 +224,34 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale.astype(x.dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding.  x: (..., S, n, hd); positions: (..., S) int32."""
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         rot_dim: Optional[int] = None, pairs: str = "half") -> jax.Array:
+    """Rotary embedding.  x: (..., S, n, hd); positions: (..., S) int32.
+
+    The first ``rot_dim`` dims of each head (default: all of it) rotate at
+    frequencies ``theta ** (-2i / rot_dim)``; the rest pass through.
+    ``pairs="half"`` rotates ``(x[i], x[i + rot_dim/2])`` (rotate-half);
+    ``"interleaved"`` rotates ``(x[2i], x[2i+1])`` (GLM, GPT-J)."""
     hd = x.shape[-1]
-    half = hd // 2
+    rot = hd if rot_dim is None else rot_dim
+    half = rot // 2
     freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
     angles = positions[..., None].astype(jnp.float32) * freqs          # (..., S, half)
     cos = jnp.cos(angles)[..., None, :]                                 # (..., S, 1, half)
     sin = jnp.sin(angles)[..., None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    out = jnp.concatenate([
-        x1 * cos.astype(x.dtype) - x2 * sin.astype(x.dtype),
-        x2 * cos.astype(x.dtype) + x1 * sin.astype(x.dtype),
-    ], axis=-1)
+    if pairs == "interleaved":
+        xr = x[..., :rot].reshape(x.shape[:-1] + (half, 2))
+        x1, x2 = xr[..., 0], xr[..., 1]
+    else:
+        x1, x2 = x[..., :half], x[..., half:rot]
+    r1 = x1 * cos.astype(x.dtype) - x2 * sin.astype(x.dtype)
+    r2 = x2 * cos.astype(x.dtype) + x1 * sin.astype(x.dtype)
+    if pairs == "interleaved":
+        out = jnp.stack([r1, r2], axis=-1).reshape(x.shape[:-1] + (rot,))
+    else:
+        out = jnp.concatenate([r1, r2], axis=-1)
+    if rot < hd:
+        out = jnp.concatenate([out, x[..., rot:]], axis=-1)
     return out
 
 

@@ -237,7 +237,7 @@ def _block(cfg: ArchConfig, lp, x, *, positions, cache=None, pos=None,
     attn_out, new_cache = dense._attn(cfg, lp, x, positions=positions, cache=cache,
                                       pos=pos, q_block=q_block, unroll=unroll)
     x = x + attn_out
-    h = rms_norm(x, lp["mlp_norm"])
+    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     y, aux = moe_mlp(h, _moe_wts(lp), cfg.moe, _padded_experts(cfg))
     return x + y, new_cache, aux
 
@@ -259,7 +259,7 @@ def forward(cfg: ArchConfig, params, tokens, *, unroll: int = 1, q_block: int = 
 
     fn = jax.checkpoint(body) if remat else body
     (x, aux), caches = jax.lax.scan(fn, (x, jnp.float32(0.0)), stack, unroll=unroll)
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return x, caches, aux / cfg.n_layers
 
 
@@ -301,7 +301,7 @@ def decode_step(cfg: ArchConfig, params, token, cache, pos, *, unroll: int = 1):
         return constrain_activation(x), (ck, cv)
 
     x, (ck, cv) = jax.lax.scan(body, x, (stack, cache["k"], cache["v"]), unroll=unroll)
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return dense.logits_fn(cfg, params, x), {"k": ck, "v": cv}
 
 
@@ -375,7 +375,7 @@ def prefill_chunk(cfg: ArchConfig, params, tokens, cache, pos, *,
 
     x, (ck, cv) = jax.lax.scan(body, x, (stack, cache["k"], cache["v"]),
                                unroll=unroll)
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return dense.logits_fn(cfg, params, x), {"k": ck, "v": cv}
 
 
@@ -393,7 +393,7 @@ init_kv_pool = dense.init_kv_pool
 def _paged_block(cfg: ArchConfig, lp, x, *, pc, bt, pos):
     attn_out, new = dense._paged_attn(cfg, lp, x, pc=pc, bt=bt, pos=pos)
     x = x + attn_out
-    h = rms_norm(x, lp["mlp_norm"])
+    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     y, _ = moe_mlp(h, _moe_wts(lp), cfg.moe, _padded_experts(cfg))
     return x + y, new
 
@@ -413,7 +413,7 @@ def paged_decode_step(cfg: ArchConfig, params, token, pool, bt, pos, *,
 
     x, out = jax.lax.scan(body, x, (stack, *[pool[k] for k in keys]),
                           unroll=unroll)
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return dense.logits_fn(cfg, params, x), dict(zip(keys, out))
 
 
@@ -432,5 +432,5 @@ def paged_prefill_chunk(cfg: ArchConfig, params, tokens, pool, bt, pos, *,
 
     x, out = jax.lax.scan(body, x, (stack, *[pool[k] for k in keys]),
                           unroll=unroll)
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return dense.logits_fn(cfg, params, x), dict(zip(keys, out))

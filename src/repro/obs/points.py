@@ -178,6 +178,7 @@ INFORMATIONAL_POINTS: Dict[str, List[str]] = {
     ],
     "metrics": [
         "attn.form",                # trace-time: one per traced attention
+        "attn.rope",                # trace-time: one per traced dense attention
         "decode.calls",             # scheduler chunking detail
         "fleet.admission_rejects",  # admission-gate vetoes (chaos/test seam)
         "fleet.handoff_bytes",      # disaggregated fleets only
