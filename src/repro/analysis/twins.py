@@ -14,9 +14,11 @@ body, see docs/STATIC_ANALYSIS.md):
 
 * routing primitives are dropped — gather/scatter/dynamic-slice/reshape
   and friends.  The paged twins route K/V through block tables
-  (``gather_blocks``/``scatter_blocks``) where the slot path uses
-  ``update_kv_cache``; ``resident_block`` slices its layer's cache rows
-  with ``dynamic_index_in_dim`` where the scan feeds them as xs.  Routing
+  (``gather_blocks``/``scatter_blocks``) where the slot steps scatter the
+  step's rows into the cache stack their scan carries
+  (``write_kv_rows``); ``resident_block`` slices its layer's cache with
+  ``dynamic_index_in_dim``, writes it with ``update_kv_cache`` and puts
+  it back whole.  Routing
   moves bytes; it cannot change values, so it is exempt by construction.
 * non-float and scalar outputs are dropped — the twins compute positions
   and masks locally (integer ops) and the MoE scan carries a scalar aux

@@ -16,7 +16,7 @@ from repro.obs import metrics as obs_metrics
 from .layers import (QT, Schema, Spec, gather_blocks, gqa_attention,
                      init_params, kv_dequantize, kv_quantize, matmul, rms_norm,
                      rope, scatter_blocks, softmax_xent, swiglu, take_rows,
-                     update_kv_cache)
+                     update_kv_cache, write_kv_rows)
 
 
 def schema(cfg: ArchConfig) -> Schema:
@@ -102,7 +102,8 @@ def _qkv(cfg: ArchConfig, lp: Dict[str, Any], x: jax.Array, positions):
 
 
 def _attn(cfg: ArchConfig, lp: Dict[str, Any], x: jax.Array, *, positions,
-          cache: Optional[Tuple] = None, pos=None, q_block: int = 0, unroll: int = 1):
+          cache: Optional[Tuple] = None, pos=None, layer=None, q_block: int = 0,
+          unroll: int = 1):
     """Attention sub-block; returns (out, new_cache).
 
     ``cache`` is (k, v) bf16 or (k, v, k_scale, v_scale) for the int8 cache.
@@ -110,6 +111,11 @@ def _attn(cfg: ArchConfig, lp: Dict[str, Any], x: jax.Array, *, positions,
     (slot batch, one independent position per row).  With a cache present,
     ``S`` may exceed 1: the chunk is written at ``[pos, pos + S)`` and
     attended causally against the whole cache (chunked prefill).
+
+    Without ``layer`` the cache leaves are one layer's (B, T, KV, ·).  With
+    ``layer`` they are the whole layer stack (L, B, T, KV, ·): the step's
+    rows are written at layer ``layer`` first and that layer is read back
+    after, so a scan that carries the stack updates it in place.
     """
     B, S, D = x.shape
     H, hd = cfg.n_heads, cfg.hd
@@ -117,27 +123,35 @@ def _attn(cfg: ArchConfig, lp: Dict[str, Any], x: jax.Array, *, positions,
     if cache is None:
         attn = gqa_attention(q, k, v, causal=True, q_block=q_block, unroll=unroll)
         new_cache = (k, v)
-    elif len(cache) == 4:
-        kq, ks = quantize_kv(k)
-        vq, vs = quantize_kv(v)
-        ck, cv = update_kv_cache(cache[0], cache[1], kq, vq, pos)
-        cks, cvs = update_kv_cache(cache[2], cache[3], ks, vs, pos)
-        attn = gqa_attention(q, dequantize_kv(ck, cks), dequantize_kv(cv, cvs),
-                             causal=S > 1, q_offset=pos, kv_len=pos + S)
-        new_cache = (ck, cv, cks, cvs)
     else:
-        ck, cv = update_kv_cache(cache[0], cache[1], k, v, pos)
+        if len(cache) == 4:
+            (kq, ks), (vq, vs) = quantize_kv(k), quantize_kv(v)
+            rows = (kq, vq, ks, vs)
+        else:
+            rows = (k, v)
+        if layer is None:
+            new_cache = update_kv_cache(cache[0], cache[1], rows[0], rows[1], pos)
+            if len(rows) == 4:
+                new_cache += update_kv_cache(cache[2], cache[3], rows[2],
+                                             rows[3], pos)
+            c = new_cache
+        else:
+            new_cache = tuple(write_kv_rows(cl, r, layer, pos)
+                              for cl, r in zip(cache, rows))
+            c = tuple(jax.lax.dynamic_index_in_dim(cl, layer, 0, keepdims=False)
+                      for cl in new_cache)
+        ck, cv = c[:2] if len(c) == 2 else (dequantize_kv(c[0], c[2]),
+                                            dequantize_kv(c[1], c[3]))
         attn = gqa_attention(q, ck, cv, causal=S > 1, q_offset=pos,
                              kv_len=pos + S)
-        new_cache = (ck, cv)
     out = matmul(attn.reshape(B, S, H * hd), lp["wo"])
     return out, new_cache
 
 
 def _block(cfg: ArchConfig, lp, x, *, positions, cache=None, pos=None,
-           q_block=0, unroll=1):
+           layer=None, q_block=0, unroll=1):
     attn_out, new_cache = _attn(cfg, lp, x, positions=positions, cache=cache, pos=pos,
-                                q_block=q_block, unroll=unroll)
+                                layer=layer, q_block=q_block, unroll=unroll)
     x = x + attn_out
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     x = x + swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
@@ -229,26 +243,43 @@ def prefill(cfg: ArchConfig, params, tokens, *, max_len: Optional[int] = None,
     return logits, cache
 
 
+def _cache_keys(cache) -> Tuple[str, ...]:
+    return ("k", "v", "k_scale", "v_scale") if "k_scale" in cache \
+        else ("k", "v")
+
+
+def _cached_layers(cfg: ArchConfig, params, tokens, cache, pos, unroll):
+    """Embed, the layer scan of `decode_step` and `prefill_chunk`, the head.
+
+    The layer-stacked cache rides in the scan's carry next to ``x``; the
+    weight stack and the layer index are the scanned inputs.  Each layer
+    writes only its step rows into the carried stack and reads its own layer
+    back, so XLA updates the donated cache in place.  (Scanned as input and
+    returned as output, the stack would be copied whole, cut out and written
+    back whole every step.)"""
+    from repro.distributed.ctx import constrain_activation
+    x = constrain_activation(take_rows(params["embed"], tokens))
+    positions = jnp.asarray(pos)[..., None] + jnp.arange(tokens.shape[1])
+    keys = _cache_keys(cache)
+
+    def body(carry, xs):
+        x, c = carry
+        lp, l = xs
+        x, c = _block(cfg, lp, x, positions=positions, cache=c, pos=pos,
+                      layer=l)
+        return (constrain_activation(x), c), None
+
+    layers = jnp.arange(cache["k"].shape[0])
+    (x, out), _ = jax.lax.scan(body, (x, tuple(cache[k] for k in keys)),
+                               (_layer_stack(params), layers), unroll=unroll)
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return logits_fn(cfg, params, x), dict(zip(keys, out))
+
+
 def decode_step(cfg: ArchConfig, params, token, cache, pos, *, unroll: int = 1):
     """One generation step.  token: (B, 1) int32; pos: scalar position shared by
     the whole batch (lockstep) or (B,) per-slot positions (continuous batch)."""
-    from repro.distributed.ctx import constrain_activation
-    B = token.shape[0]
-    x = constrain_activation(take_rows(params["embed"], token))
-    positions = jnp.asarray(pos)[..., None] + jnp.arange(1)   # (1,) or (B, 1)
-    stack = _layer_stack(params)
-    q8 = "k_scale" in cache
-
-    def body(x, xs):
-        lp, *c = xs
-        x, c = _block(cfg, lp, x, positions=positions, cache=tuple(c), pos=pos)
-        return constrain_activation(x), c
-
-    keys = ("k", "v", "k_scale", "v_scale") if q8 else ("k", "v")
-    x, out = jax.lax.scan(body, x, (stack, *[cache[k] for k in keys]),
-                          unroll=unroll)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return logits_fn(cfg, params, x), dict(zip(keys, out))
+    return _cached_layers(cfg, params, token, cache, pos, unroll)
 
 
 # ------------------------------------------------- compressed-resident serving
@@ -307,8 +338,7 @@ def resident_block(cfg: ArchConfig, lp, x, cache, l, pos):
     from repro.distributed.ctx import constrain_activation
     S = x.shape[1]
     positions = jnp.asarray(pos)[..., None] + jnp.arange(S)   # (S,) or (B, S)
-    keys = ("k", "v", "k_scale", "v_scale") if "k_scale" in cache \
-        else ("k", "v")
+    keys = _cache_keys(cache)
     c = tuple(jax.lax.dynamic_index_in_dim(cache[k], l, 0, keepdims=False)
               for k in keys)
     x, c = _block(cfg, lp, x, positions=positions, cache=c, pos=pos)
@@ -323,8 +353,9 @@ def prefill_chunk(cfg: ArchConfig, params, tokens, cache, pos, *,
     """Chunked prefill: write one prompt chunk into an existing slotted cache.
 
     tokens: (B, S) int32 chunk; cache: ``init_cache``-layout pytree; pos: (B,)
-    int32 per-slot write offsets (the chunk occupies cache rows
-    ``[pos, pos + S)``; the caller guarantees ``pos + S <= max_len``).
+    int32 per-slot write offsets, or a scalar one for every row (the chunk
+    occupies cache rows ``[pos, pos + S)``; the caller guarantees
+    ``pos + S <= max_len``).
     Returns (logits for every chunk position (B, S, V), cache) — the caller
     picks the logit at the request's true last prompt position, so ragged
     prompts ride in fixed-shape chunks (pad tokens land in the cache but stay
@@ -335,23 +366,7 @@ def prefill_chunk(cfg: ArchConfig, params, tokens, cache, pos, *,
     stepping between chunks, then the filled cache rows are spliced into a
     free slot.
     """
-    from repro.distributed.ctx import constrain_activation
-    B, S = tokens.shape
-    x = constrain_activation(take_rows(params["embed"], tokens))
-    positions = pos[:, None] + jnp.arange(S)                  # (B, S)
-    stack = _layer_stack(params)
-    q8 = "k_scale" in cache
-
-    def body(x, xs):
-        lp, *c = xs
-        x, c = _block(cfg, lp, x, positions=positions, cache=tuple(c), pos=pos)
-        return constrain_activation(x), c
-
-    keys = ("k", "v", "k_scale", "v_scale") if q8 else ("k", "v")
-    x, out = jax.lax.scan(body, x, (stack, *[cache[k] for k in keys]),
-                          unroll=unroll)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return logits_fn(cfg, params, x), dict(zip(keys, out))
+    return _cached_layers(cfg, params, tokens, cache, pos, unroll)
 
 
 # ------------------------------------------------------------- paged KV cache

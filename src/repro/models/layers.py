@@ -385,6 +385,32 @@ def update_kv_cache(cache_k: jax.Array, cache_v: jax.Array, k: jax.Array, v: jax
     return ck, cv
 
 
+def write_kv_rows(cache: jax.Array, rows: jax.Array, layer: jax.Array,
+                  pos: jax.Array) -> jax.Array:
+    """Write one step's rows (B, S, KV, ·) into layer ``layer`` of a
+    layer-stacked cache leaf (L, B, T, KV, ·): row b lands at
+    ``[pos_b, pos_b + S)``, ``pos`` scalar (lockstep) or ``(B,)`` (slots).
+
+    One scatter of B windows whose start indices clamp the way
+    ``dynamic_update_slice`` clamps them, so every row lands exactly where
+    :func:`update_kv_cache` puts it on the layer's own slice.  Nothing else
+    of the stack is touched, so a scan that carries the stack updates it in
+    place.
+    """
+    B = rows.shape[0]
+    idx = jnp.stack([
+        jnp.broadcast_to(jnp.asarray(layer, jnp.int32), (B,)),
+        jnp.arange(B, dtype=jnp.int32),
+        jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,)),
+    ], axis=-1)                                          # (B, 3)
+    dnums = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(1, 2, 3), inserted_window_dims=(0, 1),
+        scatter_dims_to_operand_dims=(0, 1, 2))
+    return jax.lax.scatter(cache, idx, rows.astype(cache.dtype), dnums,
+                           indices_are_sorted=True, unique_indices=True,
+                           mode=jax.lax.GatherScatterMode.CLIP)
+
+
 # ------------------------------------------------------------- paged KV cache
 #
 # Block-pool primitives for the paged KV cache (docs/KV_CACHE.md): the pool

@@ -248,3 +248,55 @@ def test_moe_dispatch_matches_dense_ffn_when_experts_identical():
     np.testing.assert_allclose(np.asarray(y, np.float32), want, atol=0.1,
                                rtol=0.1)
     assert np.isfinite(float(aux))
+
+
+def _layer_by_layer(cfg, params, tokens, cache, pos):
+    """The same step driven one layer at a time through `resident_block`."""
+    x = jax.jit(lambda p, t: dense.embed_step(cfg, p, t))(params, tokens)
+    block = jax.jit(lambda lp, x, c, l, s: dense.resident_block(cfg, lp, x, c,
+                                                                l, s))
+    stack = dense._layer_stack(params)
+    for l in range(cfg.n_layers):
+        lp = {k: v[l] for k, v in stack.items()}
+        x, cache = block(lp, x, cache, jnp.int32(l), pos)
+    return jax.jit(lambda p, x: dense.head_step(cfg, p, x))(params, x), cache
+
+
+@pytest.mark.parametrize("kv_bits", [16, 8])
+@pytest.mark.parametrize("pos_form", ["scalar", "slots"])
+@pytest.mark.parametrize("S", [1, 4])
+def test_cached_step_writes_only_its_rows_and_matches_layer_by_layer(
+        S, pos_form, kv_bits):
+    """`decode_step` (S=1) and `prefill_chunk` (S=4) carry the layer-stacked
+    cache through their scan: the returned cache differs from the one passed
+    in only at rows [pos_b, pos_b + S) of each layer and slot, and logits and
+    cache are bitwise those of the step driven layer by layer."""
+    cfg = registry.reduced(registry.get("qwen3-1.7b"))
+    params = dense.init(cfg, jax.random.PRNGKey(0))
+    B, T = 3, 24
+    cache = dense.init_cache(cfg, B, T, kv_bits=kv_bits)
+    keys = jax.random.split(jax.random.PRNGKey(1), len(cache))
+    cache = {name: jax.random.randint(r, a.shape, -127, 128, jnp.int8)
+             if a.dtype == jnp.int8
+             else jax.random.normal(r, a.shape).astype(a.dtype)
+             for r, (name, a) in zip(keys, cache.items())}
+    pos = jnp.int32(5) if pos_form == "scalar" \
+        else jnp.array([0, 9, T - S], jnp.int32)
+    tokens = jnp.arange(1, B * S + 1, dtype=jnp.int32).reshape(B, S)
+    step = dense.decode_step if S == 1 else dense.prefill_chunk
+    logits, out = jax.jit(lambda p, t, c, s: step(cfg, p, t, c, s))(
+        params, tokens, cache, pos)
+    want_logits, want = _layer_by_layer(cfg, params, tokens, cache, pos)
+
+    assert logits.shape == (B, S, cfg.padded_vocab())
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(want_logits))
+    starts = np.broadcast_to(np.asarray(pos), (B,))
+    written = np.zeros((B, T), bool)
+    for b, p in enumerate(starts):
+        written[b, p:p + S] = True
+    for k in cache:
+        got, before = np.asarray(out[k]), np.asarray(cache[k])
+        np.testing.assert_array_equal(got, np.asarray(want[k]), err_msg=k)
+        changed = (got != before).any(axis=(3, 4))          # (L, B, T)
+        assert not (changed & ~written).any(), k
+        assert changed[:, written].any(), k

@@ -17,9 +17,11 @@ process may load the TPU library, and every test worker imports this file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -129,6 +131,40 @@ def test_qwen3_cached_decode_block_compiles(one_chip):
     # attention reads K/V as stored: no temporary as large as one layer's
     # K cache (the per-head widening held two of them)
     assert mem.temp_size_in_bytes < B * T * cfg.n_kv_heads * cfg.hd * 2
+
+
+def test_qwen3_decode_step_updates_donated_cache_in_place(one_chip):
+    """The whole-tree decode step at the ``qwen3-1.7b.chat`` cell's shapes
+    (published widths, 4 layers, 32 slots, 1536 cache rows, float32
+    weights), jitted with the cache donated as the engine jits it: the
+    layer scan carries the stacked cache and writes only the step's rows,
+    so no temporary holds a copy of the stack."""
+    from repro.configs import registry
+    from repro.models import dense
+    cfg = registry.published(registry.get("qwen3-1.7b"), 4)
+    B, T = 32, 1536
+
+    def f32(s):                       # the cell serves float32 weights
+        floating = jnp.issubdtype(s.dtype, jnp.floating)
+        return _sds(one_chip, s.shape, jnp.float32 if floating else s.dtype)
+
+    params = jax.tree.map(f32, jax.eval_shape(
+        functools.partial(dense.init, cfg), jax.random.PRNGKey(0)))
+    cache = jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype),
+                         jax.eval_shape(lambda: dense.init_cache(cfg, B, T)))
+    c = jax.jit(functools.partial(dense.decode_step, cfg),
+                donate_argnums=(2,)).lower(
+        params, _sds(one_chip, (B, 1), jnp.int32), cache,
+        _sds(one_chip, (B,), jnp.int32)).compile()
+    stack = cache["k"].shape
+    assert stack == (4, B, T, cfg.n_kv_heads, cfg.hd)
+    kv_bytes = 2 * 2 * int(np.prod(stack))              # K and V, bf16
+    assert c.memory_analysis().temp_size_in_bytes < kv_bytes
+    # no copy of the stack, nor a buffer allocated to hold one
+    whole = re.escape("bf16[%s]" % ",".join(map(str, stack)))
+    bulk = re.findall(r"%\S+ = " + whole + r"\{[^}]*\} (?:copy|custom-call)\(",
+                      c.as_text())
+    assert not bulk, bulk
 
 
 # Mosaic's gather lowering refuses the per-lane LUT reads: an AssertionError
