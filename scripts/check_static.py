@@ -2,11 +2,8 @@
 """repro-lint driver: run the custom static-analysis checkers (CI
 ``static-smoke``; docs/STATIC_ANALYSIS.md).
 
-Five checkers prove invariants the functional tests only sample:
+Four checkers prove invariants the functional tests only sample:
 
-* ``twin-consistency``  — resident_*/paged_* twins trace to the same
-  canonical op sequence as their scan bodies (the bit-identity hazard
-  ROADMAP names, caught at analysis time).
 * ``dtype-discipline``  — dequant affine arithmetic is f32; bf16 appears
   only as a dot operand (the PR-4 rule).
 * ``jit-host-boundary`` — no obs spans/metrics, ``.item()``, numpy host

@@ -1,8 +1,8 @@
 """repro-lint: custom static analysis for the repo's unwritten invariants.
 
 The checkers (docs/STATIC_ANALYSIS.md) turn conventions that were previously
-enforced only by runtime tests — twin bit-identity, f32 dequant discipline,
-no host work inside jit, lock discipline, obs-catalog sync — into
+enforced only by runtime tests — f32 dequant discipline, no host work
+inside jit, lock discipline, obs-catalog sync — into
 machine-checked rules gating CI before any test runs.
 
 Import surface is deliberately tiny and stdlib-only; checker modules load

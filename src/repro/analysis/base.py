@@ -8,9 +8,9 @@ print the rest as uniform ``file:line rule message`` text or ``--json``.
 
 Deliberately stdlib-only — the ``docs-check`` CI job runs ``check_docs.py``
 on a bare interpreter (no jax/numpy install), so importing this module must
-never pull the scientific stack.  Checkers that need jax (twin-consistency)
-import it lazily inside their ``check()`` function; the registry maps
-checker names to ``"module:function"`` strings resolved only when run.
+never pull the scientific stack.  A checker that needs jax imports it
+lazily inside its ``check()`` function; the registry maps checker names
+to ``"module:function"`` strings resolved only when run.
 """
 from __future__ import annotations
 
@@ -134,7 +134,6 @@ def render_json(findings: Iterable[Finding], *,
 # returns List[Finding].  Strings (not callables) keep this module free of
 # checker imports — resolve() is the only place a checker module loads.
 CHECKERS: Dict[str, str] = {
-    "twin-consistency": "repro.analysis.twins:check",
     "dtype-discipline": "repro.analysis.dtypes:check",
     "jit-host-boundary": "repro.analysis.jit_boundary:check",
     "lock-discipline": "repro.analysis.locks:check",

@@ -5,7 +5,8 @@ dequantization affine ``q * scale + zero`` must be computed in float32 —
 bf16's 8-bit mantissa rounds the reconstruction grid — and bfloat16 may
 appear only as the *operand dtype of the MXU dot* (cast after the affine).
 
-This is an AST pass over ``kernels/`` and ``models/layers.py``.  It finds
+This is an AST pass over ``kernels/``, ``models/layers.py`` and
+``models/kv.py`` (the KV cache layouts).  It finds
 affine-dequant expressions (an ``Add`` whose left operand is a ``Mult``)
 and resolves the compute dtype of each factor through a per-function
 symbol table:
@@ -30,7 +31,8 @@ from typing import Dict, List, Optional
 
 from .base import Finding, iter_py_files, rel
 
-TARGET_GLOBS = ["src/repro/kernels/*.py", "src/repro/models/layers.py"]
+TARGET_GLOBS = ["src/repro/kernels/*.py", "src/repro/models/layers.py",
+                "src/repro/models/kv.py"]
 
 F32, BF16, DYN = "float32", "bfloat16", "dynamic"
 _DTYPE_ATTRS = {"float32": F32, "bfloat16": BF16, "float16": BF16}

@@ -354,7 +354,7 @@ class KVCompressionSpec:
     * ``bits`` — in-pool precision: 16 keeps dense bf16 blocks (paged layout
       only, bit-identical to the slot pool), 8/4 quantize each block's K/V
       per (token, head) with an asymmetric grid
-      (:func:`repro.models.layers.kv_quantize` — the jnp twin of
+      (:func:`repro.models.kv.kv_quantize` — the jnp twin of
       :func:`repro.core.quant.quantize`'s ASYMMETRIC scheme);
     * ``block_size`` — tokens per block (the paging granularity);
     * ``codec`` — optional cold-tier entropy codec (``huffman`` / ``rans`` /

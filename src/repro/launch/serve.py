@@ -213,9 +213,8 @@ def parse_args(argv=None) -> argparse.Namespace:
                     "--batch-slots (the paged KV cache is a "
                     "continuous-batching feature; docs/KV_CACHE.md)")
         if args.resident != "dense":
-            p.error("paged KV (--kv-spec) needs --resident dense: the "
-                    "compressed-resident per-layer drivers have no paged "
-                    "step twins yet")
+            p.error("paged KV (--kv-spec) needs --resident dense: paged "
+                    "x compressed residency is not enabled yet")
         if args.mesh:
             p.error("paged KV (--kv-spec) is single-device today; drop "
                     "--mesh")

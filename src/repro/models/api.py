@@ -8,16 +8,19 @@
     init_cache(cfg, batch, max_len) -> cache pytree
     cache_specs(cfg) -> logical axes for cache leaves
 
-Attention-cache families (dense, moe) additionally expose the slot-batch
-contract used by continuous-batching serving (``serving/batching``):
+Attention-cache families (dense, moe) additionally expose the cached
+layer step (``kv.py``'s layouts, one layer scan — see ``dense.py``):
 
-    prefill_chunk(cfg, params, tokens, cache, pos, *, ...) -> (logits, cache)
-        — chunked prefill at per-slot (B,) write offsets
-    decode_step(..., pos=(B,) array)
-        — one fused step over a slot batch with ragged per-slot kv_len
+    decode_step(cfg, params, token, cache, pos, table=None, *, ...)
+    prefill_chunk(cfg, params, tokens, cache, pos, table=None, *, ...)
+        — ``pos`` scalar (lockstep) or (B,) per-slot (continuous
+        batching); ``table`` routes through a paged block pool
+    init_kv_pool(cfg, n_blocks, block_size, kv_bits) -> paged pool
+    embed / head / prefill_layer / cached_layer
+        — the per-layer pieces compressed-resident serving jits
 
-``supports_continuous_batching(cfg)`` reports whether a family implements it
-(recurrent caches — ssm/hybrid conv+state, encdec cross-attention — need a
+``has_cached_step(cfg)`` reports whether a family has it (recurrent
+caches — ssm/hybrid conv+state, encdec cross-attention — need a
 family-specific slot layout and are not wired up yet).
 """
 from __future__ import annotations
@@ -41,35 +44,11 @@ def build(cfg: ArchConfig) -> types.ModuleType:
     }[cfg.family]
 
 
-def supports_continuous_batching(cfg: ArchConfig) -> bool:
-    """True when the family implements the slot-batch cache contract
-    (``prefill_chunk`` + per-slot ``decode_step`` positions)."""
-    return hasattr(build(cfg), "prefill_chunk")
-
-
-def supports_paged_kv(cfg: ArchConfig) -> bool:
-    """True when the family implements the paged block-pool cache contract
-    (``init_kv_pool`` + ``paged_prefill_chunk`` / ``paged_decode_step``
-    routing K/V through a block table — see docs/KV_CACHE.md)."""
-    return hasattr(build(cfg), "paged_decode_step")
-
-
-def supports_resident_serving(cfg: ArchConfig) -> bool:
-    """True when the family implements the per-layer weight-slot contract
-    of compressed-resident serving (``embed_step`` / ``head_step`` /
-    ``resident_prefill_block`` / ``resident_block`` — see
-    docs/SERVING.md §"Compressed-resident serving"); dense and moe today."""
-    return hasattr(build(cfg), "resident_block")
-
-
-def supports_fused_resident(cfg: ArchConfig) -> bool:
-    """True when the family's per-layer drivers can consume fused payload
-    handles (:class:`repro.kernels.fused_decode_matmul.FusedQT`) in their
-    weight-slot dicts.  Any family meeting the resident contract qualifies:
-    the drivers route every weight through ``layers.matmul``, which
-    dispatches FusedQT slots to the fused decode→dequant→matmul kernel
-    (tensors the fused tile contract rejects simply stay QT slots)."""
-    return supports_resident_serving(cfg)
+def has_cached_step(cfg: ArchConfig) -> bool:
+    """True when the family has the cached layer step, which is what
+    continuous batching, the paged KV pool and compressed-resident serving
+    (fused or not) all run."""
+    return hasattr(build(cfg), "cached_layer")
 
 
 def cache_specs(cfg: ArchConfig, **kw) -> Dict[str, Tuple]:

@@ -385,109 +385,6 @@ def update_kv_cache(cache_k: jax.Array, cache_v: jax.Array, k: jax.Array, v: jax
     return ck, cv
 
 
-def write_kv_rows(cache: jax.Array, rows: jax.Array, layer: jax.Array,
-                  pos: jax.Array) -> jax.Array:
-    """Write one step's rows (B, S, KV, ·) into layer ``layer`` of a
-    layer-stacked cache leaf (L, B, T, KV, ·): row b lands at
-    ``[pos_b, pos_b + S)``, ``pos`` scalar (lockstep) or ``(B,)`` (slots).
-
-    One scatter of B windows whose start indices clamp the way
-    ``dynamic_update_slice`` clamps them, so every row lands exactly where
-    :func:`update_kv_cache` puts it on the layer's own slice.  Nothing else
-    of the stack is touched, so a scan that carries the stack updates it in
-    place.
-    """
-    B = rows.shape[0]
-    idx = jnp.stack([
-        jnp.broadcast_to(jnp.asarray(layer, jnp.int32), (B,)),
-        jnp.arange(B, dtype=jnp.int32),
-        jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,)),
-    ], axis=-1)                                          # (B, 3)
-    dnums = jax.lax.ScatterDimensionNumbers(
-        update_window_dims=(1, 2, 3), inserted_window_dims=(0, 1),
-        scatter_dims_to_operand_dims=(0, 1, 2))
-    return jax.lax.scatter(cache, idx, rows.astype(cache.dtype), dnums,
-                           indices_are_sorted=True, unique_indices=True,
-                           mode=jax.lax.GatherScatterMode.CLIP)
-
-
-# ------------------------------------------------------------- paged KV cache
-#
-# Block-pool primitives for the paged KV cache (docs/KV_CACHE.md): the pool
-# leaf layout is (n_blocks, block_size, KV, hd_store) per layer, and a
-# request's logical sequence is the concatenation of the blocks its
-# (max_blocks,)-row of the block table names.  Block id 0 is the TRASH block:
-# inactive lanes of the fused decode step point every table entry at it, so
-# their garbage writes land in memory no request ever gathers as live rows.
-
-
-def gather_blocks(pool_leaf: jax.Array, bt: jax.Array) -> jax.Array:
-    """Gather per-request sequences out of a block pool.
-
-    pool_leaf: (NB, BS, ...) one layer's pool; bt: (B, MB) int32 block table.
-    Returns (B, MB * BS, ...) — each request's blocks concatenated in table
-    order, ready to stand in for the slot cache's (B, T, ...) axis (positions
-    >= kv_len are masked by attention exactly like slot-pool padding).
-    """
-    g = jnp.take(pool_leaf, bt, axis=0)                  # (B, MB, BS, ...)
-    return g.reshape((bt.shape[0], -1) + pool_leaf.shape[2:])
-
-
-def scatter_blocks(pool_leaf: jax.Array, bt: jax.Array, positions: jax.Array,
-                   val: jax.Array) -> jax.Array:
-    """Write per-request rows into the pool through the block table.
-
-    pool_leaf: (NB, BS, ...); bt: (B, MB); positions: (B, S) global token
-    positions; val: (B, S, ...).  Row (b, s) lands at block
-    ``bt[b, positions[b, s] // BS]``, offset ``positions[b, s] % BS``.
-    Duplicate targets only arise from trash-block writes (several idle lanes
-    aiming at block 0), where any winner is equally garbage.
-    """
-    NB, BS = pool_leaf.shape[0], pool_leaf.shape[1]
-    blk = jnp.take_along_axis(bt, positions // BS, axis=1)      # (B, S)
-    idx = (blk * BS + positions % BS).reshape(-1)               # (B*S,)
-    flat = pool_leaf.reshape((NB * BS,) + pool_leaf.shape[2:])
-    flat = flat.at[idx].set(
-        val.reshape((-1,) + val.shape[2:]).astype(pool_leaf.dtype))
-    return flat.reshape(pool_leaf.shape)
-
-
-def kv_quantize(x: jax.Array, bits: int):
-    """Asymmetric per-(token, head) KV quantization — the jnp twin of
-    :func:`repro.core.quant.quantize` with ``Scheme.ASYMMETRIC`` at
-    per-channel granularity over head_dim, applied in-graph so paged blocks
-    quantize as they are written.
-
-    x: (..., hd) -> (q uint8 (..., hd) [or (..., hd/2) nibble-packed at
-    bits=4], scale bf16 (..., 1), zero bf16 (..., 1)).  The grid spans
-    [min, max] per (token, head) vector: KV activations are not
-    zero-centered (unlike weights), so the asymmetric grid halves the error
-    of a symmetric one at the same width.
-    """
-    assert bits in (8, 4), bits
-    xf = x.astype(jnp.float32)
-    lo = jnp.min(xf, axis=-1, keepdims=True)
-    hi = jnp.max(xf, axis=-1, keepdims=True)
-    qmax = (1 << bits) - 1
-    scale = (hi - lo) / qmax
-    scale = jnp.where(scale == 0.0, 1.0, scale)      # constant vector guard
-    q = jnp.clip(jnp.round((xf - lo) / scale), 0, qmax).astype(jnp.uint8)
-    if bits == 4:
-        q = q[..., 0::2] | (q[..., 1::2] << 4)       # nibble-pack along hd
-    return q, scale.astype(jnp.bfloat16), lo.astype(jnp.bfloat16)
-
-
-def kv_dequantize(q: jax.Array, scale: jax.Array, zero: jax.Array,
-                  bits: int) -> jax.Array:
-    """Inverse of :func:`kv_quantize`: uint8 symbols -> bf16 K/V rows."""
-    assert bits in (8, 4), bits
-    if bits == 4:
-        q = jnp.stack([q & 0xF, q >> 4], axis=-1
-                      ).reshape(q.shape[:-1] + (q.shape[-1] * 2,))
-    return q.astype(jnp.bfloat16) * scale.astype(jnp.bfloat16) \
-        + zero.astype(jnp.bfloat16)
-
-
 # ---------------------------------------------------------------------- loss helpers
 
 def softmax_xent(logits: jax.Array, labels: jax.Array, vocab: int) -> jax.Array:

@@ -23,8 +23,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, MoEConfig
-from .layers import (QT, Schema, Spec, deq, init_params, matmul, rms_norm,
-                     softmax_xent, swiglu, take_rows)
+from .layers import (Schema, Spec, deq, init_params, matmul, rms_norm,
+                     softmax_xent, swiglu)
 from . import dense
 
 
@@ -232,30 +232,49 @@ def _moe_wts(lp: Dict[str, Any]) -> Dict[str, Any]:
     return {k.split("/", 1)[1]: v for k, v in lp.items() if k.startswith("moe/")}
 
 
-def _block(cfg: ArchConfig, lp, x, *, positions, cache=None, pos=None,
-           q_block=0, unroll=1):
-    attn_out, new_cache = dense._attn(cfg, lp, x, positions=positions, cache=cache,
-                                      pos=pos, q_block=q_block, unroll=unroll)
-    x = x + attn_out
-    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    y, aux = moe_mlp(h, _moe_wts(lp), cfg.moe, _padded_experts(cfg))
-    return x + y, new_cache, aux
+def _mlp(cfg: ArchConfig, lp, h):
+    """The MoE feed-forward as ``(y, load_balance_aux)``: the MLP this
+    family hands the dense block."""
+    return moe_mlp(h, _moe_wts(lp), cfg.moe, _padded_experts(cfg))
+
+
+# The dense family's block, step pieces and cached layer scan with the MoE
+# MLP (see dense.py §step pieces).  The cached steps drop the load-balance
+# aux; serving never reads it.
+#
+# NOTE on dispatch capacity: the GShard capacity ``C`` is a function of the
+# number of tokens in flight, so a chunk and a full-prompt prefill route
+# identically only while no expert overflows — serve MoE with a
+# ``capacity_factor`` that admits the worst case (``>= E / top_k``) when
+# bit-reproducibility across batch packings matters.
+#
+# Under `CompressedResidentWeights(fused=True)` the 2-D attention weights
+# arrive as FusedQT payload handles (decoded inside `layers.matmul`); the
+# (L, E, D, F) expert stacks fail the fused tile contract (not a stacked
+# matrix) and automatically stay on the unfused per-layer decode path —
+# the per-tensor fallback `tests/differential/` pins.
+
+embed = dense.embed
+head = dense.head
+step_positions = dense.step_positions
+prefill_layer = partial(dense.prefill_layer, mlp=_mlp)
+cached_layer = partial(dense.cached_layer, mlp=_mlp)
+decode_step = partial(dense.decode_step, mlp=_mlp)
+prefill_chunk = partial(dense.prefill_chunk, mlp=_mlp)
 
 
 def forward(cfg: ArchConfig, params, tokens, *, unroll: int = 1, q_block: int = 0,
             remat: bool = False, collect_cache: bool = False):
-    from repro.distributed.ctx import constrain_activation
     B, S = tokens.shape
-    x = constrain_activation(take_rows(params["embed"], tokens))
+    x = embed(cfg, params, tokens)
     positions = jnp.arange(S)
     stack = dense._layer_stack(params)
 
     def body(carry, lp):
         x, aux_sum = carry
-        x, kv, aux = _block(cfg, lp, x, positions=positions, q_block=q_block,
-                            unroll=unroll)
-        return (constrain_activation(x), aux_sum + aux), \
-            kv if collect_cache else None
+        x, kv, aux = prefill_layer(cfg, lp, x, positions, q_block=q_block,
+                                   unroll=unroll)
+        return (x, aux_sum + aux), kv if collect_cache else None
 
     fn = jax.checkpoint(body) if remat else body
     (x, aux), caches = jax.lax.scan(fn, (x, jnp.float32(0.0)), stack, unroll=unroll)
@@ -274,6 +293,7 @@ def loss_fn(cfg: ArchConfig, params, batch, *, unroll: int = 1, q_block: int = 0
 
 init_cache = dense.init_cache
 cache_specs = dense.cache_specs
+init_kv_pool = dense.init_kv_pool
 
 
 def prefill(cfg: ArchConfig, params, tokens, *, max_len: Optional[int] = None,
@@ -286,151 +306,3 @@ def prefill(cfg: ArchConfig, params, tokens, *, max_len: Optional[int] = None,
     pad = [(0, 0), (0, 0), (0, max_len - S), (0, 0), (0, 0)]
     cache = {"k": jnp.pad(k, pad), "v": jnp.pad(v, pad)}
     return dense.logits_fn(cfg, params, x[:, -1:, :]), cache
-
-
-def decode_step(cfg: ArchConfig, params, token, cache, pos, *, unroll: int = 1):
-    from repro.distributed.ctx import constrain_activation
-    B = token.shape[0]
-    x = constrain_activation(take_rows(params["embed"], token))
-    positions = jnp.asarray(pos)[..., None] + jnp.arange(1)   # (1,) or (B, 1)
-    stack = dense._layer_stack(params)
-
-    def body(x, xs):
-        lp, ck, cv = xs
-        x, (ck, cv), _ = _block(cfg, lp, x, positions=positions, cache=(ck, cv), pos=pos)
-        return constrain_activation(x), (ck, cv)
-
-    x, (ck, cv) = jax.lax.scan(body, x, (stack, cache["k"], cache["v"]), unroll=unroll)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return dense.logits_fn(cfg, params, x), {"k": ck, "v": cv}
-
-
-# ------------------------------------------------- compressed-resident serving
-#
-# Per-layer weight-slot twins of the step functions, mirroring the dense
-# family's contract (see dense.resident_block and docs/SERVING.md
-# §"Compressed-resident serving").  The slot dict carries the `moe/*`-
-# prefixed expert weights exactly as `_layer_stack` would slice them, so
-# `_moe_wts` resolves them unchanged; the MoE cache is always the plain
-# (k, v) pair (the int8 KV path is dense-only today, as in `decode_step`).
-#
-# Under `CompressedResidentWeights(fused=True)` the 2-D attention weights
-# arrive as FusedQT payload handles (decoded inside `layers.matmul`); the
-# (L, E, D, F) expert stacks fail the fused tile contract (not a stacked
-# matrix) and automatically stay on the unfused per-layer decode path —
-# the per-tensor fallback `tests/differential/` pins.
-
-embed_step = dense.embed_step
-head_step = dense.head_step
-
-
-def resident_prefill_block(cfg: ArchConfig, lp, x, *, positions,
-                           q_block: int = 0, unroll: int = 1):
-    """One `forward`-collect-cache scan iteration; the load-balance aux is
-    dropped (serving never reads it, matching `prefill`)."""
-    from repro.distributed.ctx import constrain_activation
-    x, kv, _aux = _block(cfg, lp, x, positions=positions, q_block=q_block,
-                         unroll=unroll)
-    return constrain_activation(x), kv
-
-
-def resident_block(cfg: ArchConfig, lp, x, cache, l, pos):
-    """One `decode_step` / `prefill_chunk` scan iteration against the
-    layer-stacked cache (see :func:`dense.resident_block`)."""
-    from repro.distributed.ctx import constrain_activation
-    S = x.shape[1]
-    positions = jnp.asarray(pos)[..., None] + jnp.arange(S)   # (S,) or (B, S)
-    ck = jax.lax.dynamic_index_in_dim(cache["k"], l, 0, keepdims=False)
-    cv = jax.lax.dynamic_index_in_dim(cache["v"], l, 0, keepdims=False)
-    x, (ck, cv), _aux = _block(cfg, lp, x, positions=positions,
-                               cache=(ck, cv), pos=pos)
-    out = {
-        "k": jax.lax.dynamic_update_index_in_dim(cache["k"], ck, l, 0),
-        "v": jax.lax.dynamic_update_index_in_dim(cache["v"], cv, l, 0),
-    }
-    return constrain_activation(x), out
-
-
-def prefill_chunk(cfg: ArchConfig, params, tokens, cache, pos, *,
-                  unroll: int = 1):
-    """Chunked prefill into a slotted cache; see :func:`dense.prefill_chunk`.
-
-    NOTE on dispatch capacity: the GShard capacity ``C`` is a function of the
-    number of tokens in flight, so a chunk and a full-prompt prefill route
-    identically only while no expert overflows — serve MoE with a
-    ``capacity_factor`` that admits the worst case (``>= E / top_k``) when
-    bit-reproducibility across batch packings matters.
-    """
-    from repro.distributed.ctx import constrain_activation
-    B, S = tokens.shape
-    x = constrain_activation(take_rows(params["embed"], tokens))
-    positions = pos[:, None] + jnp.arange(S)                  # (B, S)
-    stack = dense._layer_stack(params)
-
-    def body(x, xs):
-        lp, ck, cv = xs
-        x, (ck, cv), _ = _block(cfg, lp, x, positions=positions,
-                                cache=(ck, cv), pos=pos)
-        return constrain_activation(x), (ck, cv)
-
-    x, (ck, cv) = jax.lax.scan(body, x, (stack, cache["k"], cache["v"]),
-                               unroll=unroll)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return dense.logits_fn(cfg, params, x), {"k": ck, "v": cv}
-
-
-# ------------------------------------------------------------- paged KV cache
-#
-# Paged twins of the step functions above (docs/KV_CACHE.md).  Attention is
-# shared with the dense family (`dense._paged_attn` scatters/gathers through
-# the block table); the MLP is the MoE dispatch with the load-balance aux
-# dropped, matching `decode_step`.  Quantized pools work unchanged — the
-# pool layout carries no family-specific leaves.
-
-init_kv_pool = dense.init_kv_pool
-
-
-def _paged_block(cfg: ArchConfig, lp, x, *, pc, bt, pos):
-    attn_out, new = dense._paged_attn(cfg, lp, x, pc=pc, bt=bt, pos=pos)
-    x = x + attn_out
-    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    y, _ = moe_mlp(h, _moe_wts(lp), cfg.moe, _padded_experts(cfg))
-    return x + y, new
-
-
-def paged_decode_step(cfg: ArchConfig, params, token, pool, bt, pos, *,
-                      unroll: int = 1):
-    from repro.distributed.ctx import constrain_activation
-    x = constrain_activation(take_rows(params["embed"], token))
-    stack = dense._layer_stack(params)
-    keys, _ = dense._pool_meta(cfg, pool)
-
-    def body(x, xs):
-        lp, *pc = xs
-        x, new = _paged_block(cfg, lp, x, pc=dict(zip(keys, pc)), bt=bt,
-                              pos=pos)
-        return constrain_activation(x), tuple(new[k] for k in keys)
-
-    x, out = jax.lax.scan(body, x, (stack, *[pool[k] for k in keys]),
-                          unroll=unroll)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return dense.logits_fn(cfg, params, x), dict(zip(keys, out))
-
-
-def paged_prefill_chunk(cfg: ArchConfig, params, tokens, pool, bt, pos, *,
-                        unroll: int = 1):
-    from repro.distributed.ctx import constrain_activation
-    x = constrain_activation(take_rows(params["embed"], tokens))
-    stack = dense._layer_stack(params)
-    keys, _ = dense._pool_meta(cfg, pool)
-
-    def body(x, xs):
-        lp, *pc = xs
-        x, new = _paged_block(cfg, lp, x, pc=dict(zip(keys, pc)), bt=bt,
-                              pos=pos)
-        return constrain_activation(x), tuple(new[k] for k in keys)
-
-    x, out = jax.lax.scan(body, x, (stack, *[pool[k] for k in keys]),
-                          unroll=unroll)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return dense.logits_fn(cfg, params, x), dict(zip(keys, out))

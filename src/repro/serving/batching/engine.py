@@ -84,9 +84,9 @@ def _sample_slots(logits, keys, temps, vocab):
 class ContinuousEngine:
     """Serve concurrent, independently-arriving requests over one slot batch.
 
-    Families must implement the slot-batch cache contract
-    (``api.supports_continuous_batching``): dense and moe today; recurrent
-    caches (ssm/hybrid/encdec) need family-specific slot state and raise.
+    Families must have the cached layer step (``api.has_cached_step``):
+    dense and moe today; recurrent caches (ssm/hybrid/encdec) need
+    family-specific slot state and raise.
 
     ``resident="compressed"`` serves the slot batch straight from the
     entropy-coded container — ``params`` must then be a
@@ -104,7 +104,7 @@ class ContinuousEngine:
                  kv_spec: Optional[KVCompressionSpec] = None,
                  kv_blocks: Optional[int] = None,
                  handoff_sink=None):
-        if not api.supports_continuous_batching(cfg):
+        if not api.has_cached_step(cfg):
             raise NotImplementedError(
                 f"family {cfg.family!r} does not implement the slot-batch "
                 f"cache contract (prefill_chunk + per-slot decode positions);"
@@ -145,13 +145,9 @@ class ContinuousEngine:
             ServeSteps(cfg, sc, mesh=mesh, rules=rules, resident=resident)
         self.paged = kv_spec is not None
         if self.paged:
-            # paged KV rides the block-pool step functions (docs/KV_CACHE.md)
-            if not api.supports_paged_kv(cfg):
-                raise NotImplementedError(
-                    f"family {cfg.family!r} does not implement the paged "
-                    f"block-pool cache contract (init_kv_pool + "
-                    f"paged_decode_step); supported today: dense, moe")
-            if self.steps.paged_decode_fn is None:
+            # paged KV: the same step functions, given the block table
+            # (docs/KV_CACHE.md)
+            if self.steps.resident != "dense":
                 raise NotImplementedError(
                     "paged KV needs the dense-residency whole-tree steps; "
                     "serve with resident='dense' (docs/KV_CACHE.md)")
@@ -249,15 +245,15 @@ class ContinuousEngine:
         req, chunk = st["req"], self.prefill_chunk
         P, c0 = req.prompt_len, st["c0"]
         with obs_trace.span("serve.admit_chunk", rid=req.rid, c0=c0):
+            toks = jnp.asarray(st["toks"][:, c0:c0 + chunk])
+            pos = jnp.full((1,), c0, jnp.int32)
             if self.paged:
                 bt = jnp.asarray(self.slots.table_rows([st["slot"]]))
-                logits, self.slots.pool = self.steps.paged_prefill_chunk_fn(
-                    self.params, jnp.asarray(st["toks"][:, c0:c0 + chunk]),
-                    self.slots.pool, bt, jnp.full((1,), c0, jnp.int32))
+                logits, self.slots.pool = self.steps.prefill_chunk_fn(
+                    self.params, toks, self.slots.pool, pos, bt)
             else:
                 logits, st["scratch"] = self.steps.prefill_chunk_fn(
-                    self.params, jnp.asarray(st["toks"][:, c0:c0 + chunk]),
-                    st["scratch"], jnp.full((1,), c0, jnp.int32))
+                    self.params, toks, st["scratch"], pos)
             _fence(logits)
         if c0 <= P - 1 < c0 + chunk:
             st["last"] = logits[:, P - 1 - c0][:, None]     # (1, 1, V)
@@ -336,8 +332,8 @@ class ContinuousEngine:
             tok = jnp.asarray(self._tokens[:, None])
             if self.paged:
                 bt = jnp.asarray(self.slots.decode_tables())
-                logits, self.slots.pool = self.steps.paged_decode_fn(
-                    self.params, tok, self.slots.pool, bt, pos)
+                logits, self.slots.pool = self.steps.decode_fn(
+                    self.params, tok, self.slots.pool, pos, bt)
             else:
                 logits, self.slots.cache = self.steps.decode_fn(
                     self.params, tok, self.slots.cache, pos)

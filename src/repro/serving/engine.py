@@ -245,6 +245,8 @@ class ServeSteps:
     never drift numerically and a model warm in one is warm in the other.
     ``decode_fn`` accepts ``pos`` as a scalar (lockstep) or a ``(B,)`` array
     (per-slot ragged positions) — same callable, two traced shapes.
+    ``decode_fn`` and ``prefill_chunk_fn`` take the paged pool's block
+    table as an optional fifth argument (docs/KV_CACHE.md).
 
     Multi-device: pass ``mesh`` (and optionally ``rules``) and the steps
     carry the serve sharding profile — engines call :meth:`cache_shardings`
@@ -265,11 +267,12 @@ class ServeSteps:
     drivers keep the step-function signatures, so :class:`Engine` and
     :class:`~repro.serving.batching.ContinuousEngine` drive either mode
     unchanged — and greedy decode is bit-identical between the two (the
-    per-layer blocks mirror the scan bodies op for op; see docs/SERVING.md
-    §"Compressed-resident serving").  Compressed residency is single-device
-    today (``mesh`` must stay None): per-layer decode targets the
-    bandwidth-bound single-accelerator regime the paper measures, while
-    multi-device hosts shard *decoded* weights (ARCHITECTURE.md §6).
+    per-layer loops jit the very functions the scans call; see
+    docs/SERVING.md §"Compressed-resident serving").  Compressed residency
+    is single-device today (``mesh`` must stay None): per-layer decode
+    targets the bandwidth-bound single-accelerator regime the paper
+    measures, while multi-device hosts shard *decoded* weights
+    (ARCHITECTURE.md §6).
     """
 
     def __init__(self, cfg: ArchConfig, sc: ServeConfig,
@@ -291,10 +294,10 @@ class ServeSteps:
                     "compressed-resident serving is single-device (see "
                     "docs/SERVING.md §\"Which mode when\"); drop mesh= or "
                     "use resident='dense'")
-            if not api.supports_resident_serving(cfg):
+            if not api.has_cached_step(cfg):
                 raise NotImplementedError(
                     f"family {cfg.family!r} does not implement the per-layer "
-                    f"weight-slot contract (embed_step / resident_block); "
+                    f"weight-slot contract (embed / cached_layer); "
                     f"supported today: dense, moe")
             self._build_resident_steps()
             return
@@ -313,89 +316,55 @@ class ServeSteps:
             return self.mod.prefill(cfg, params, prompt, max_len=sc.max_len,
                                     unroll=sc.unroll, q_block=sc.q_block)
 
-        def _decode(params, token, cache, pos):
-            return self.mod.decode_step(cfg, params, token, cache, pos,
+        def _decode(params, token, cache, pos, *table):
+            # table: the paged pool's block table, for the families whose
+            # decode_step takes one
+            return self.mod.decode_step(cfg, params, token, cache, pos, *table,
                                         unroll=sc.unroll)
 
         self.prefill_fn = jax.jit(scoped(_prefill), **kw)
         self.decode_fn = jax.jit(scoped(_decode), donate_argnums=(2,))
         self.prefill_chunk_fn = None
-        if hasattr(self.mod, "prefill_chunk"):
-            def _chunk(params, tokens, cache, pos):
+        if api.has_cached_step(cfg):
+            def _chunk(params, tokens, cache, pos, table=None):
                 return self.mod.prefill_chunk(cfg, params, tokens, cache, pos,
-                                              unroll=sc.unroll)
+                                              table, unroll=sc.unroll)
 
             self.prefill_chunk_fn = jax.jit(scoped(_chunk), donate_argnums=(2,))
-
-        # paged twins: the block-pool cache layout (docs/KV_CACHE.md) —
-        # same donation discipline, block table rides as an extra operand
-        self.paged_decode_fn = self.paged_prefill_chunk_fn = None
-        if hasattr(self.mod, "paged_decode_step"):
-            def _pdec(params, token, pool, bt, pos):
-                return self.mod.paged_decode_step(cfg, params, token, pool,
-                                                  bt, pos, unroll=sc.unroll)
-
-            def _pchunk(params, tokens, pool, bt, pos):
-                return self.mod.paged_prefill_chunk(cfg, params, tokens, pool,
-                                                    bt, pos, unroll=sc.unroll)
-
-            self.paged_decode_fn = jax.jit(scoped(_pdec), donate_argnums=(2,))
-            self.paged_prefill_chunk_fn = jax.jit(scoped(_pchunk),
-                                                  donate_argnums=(2,))
 
     # ------------------------------------------------- compressed residency
     def _build_resident_steps(self) -> None:
         """Per-layer jitted pieces + Python drivers (compressed residency).
 
-        Five small jitted closures replace the three whole-tree steps: embed,
-        head (and the prefill last-position variant), the cacheless prefill
-        block, the cached block shared by decode and chunked prefill, and
-        the prefill cache write.  One trace of the cached block serves every
-        layer (``l`` is a traced scalar) and every front end (S comes from
-        the token shape).  The drivers below stitch them together around the
-        weight store's prefetch/get double buffer.
+        The family's own step pieces, jitted one by one: embed, head (and
+        its last-position form for prefill), `forward`'s per-layer body, the
+        cached per-layer body shared by decode and chunked prefill, and the
+        prefill cache write (``kv.store`` at row 0).  One trace of the
+        cached body serves every layer (``l`` is a traced scalar) and every
+        front end (S comes from the token shape).  The loops below stitch
+        them together around the weight store's prefetch/get double buffer.
         """
+        from repro.models import kv
         cfg, sc, mod = self.cfg, self.sc, self.mod
 
-        def _embed(g, tokens):
-            return mod.embed_step(cfg, g, tokens)
-
-        def _head(g, x):
-            return mod.head_step(cfg, g, x)
-
-        def _head_last(g, x):
-            return mod.head_step(cfg, g, x, last_only=True)
-
         def _pblock(lp, x, positions):
-            return mod.resident_prefill_block(
-                cfg, lp, x, positions=positions, q_block=sc.q_block,
-                unroll=sc.unroll)
+            x, kv_rows, _aux = mod.prefill_layer(
+                cfg, lp, x, positions, q_block=sc.q_block, unroll=sc.unroll)
+            return x, kv_rows
 
-        def _rblock(lp, x, cache, l, pos):
-            return mod.resident_block(cfg, lp, x, cache, l, pos)
-
-        def _write(cache, k, v, l):
-            out = dict(cache)
-            for key, val in (("k", k), ("v", v)):
-                out[key] = jax.lax.dynamic_update_slice(
-                    cache[key], val[None].astype(cache[key].dtype),
-                    (l,) + (0,) * (cache[key].ndim - 1))
-            return out
-
-        self._embed_fn = jax.jit(_embed)
-        self._head_fn = jax.jit(_head)
-        self._head_last_fn = jax.jit(_head_last)
+        self._embed_fn = jax.jit(partial(mod.embed, cfg))
+        self._head_fn = jax.jit(partial(mod.head, cfg))
+        self._head_last_fn = jax.jit(partial(mod.head, cfg, last_only=True))
         self._pblock_fn = jax.jit(_pblock)
-        self._rblock_fn = jax.jit(_rblock, donate_argnums=(2,))
-        self._write_fn = jax.jit(_write, donate_argnums=(0,))
+        self._rblock_fn = jax.jit(partial(mod.cached_layer, cfg),
+                                  donate_argnums=(2,))
+        self._write_fn = jax.jit(kv.store, donate_argnums=(0,))
         self.prefill_fn = self._resident_prefill
         self.decode_fn = self._resident_step
         self.prefill_chunk_fn = self._resident_step
-        # paged KV is a dense-residency feature (docs/KV_CACHE.md)
-        self.paged_decode_fn = self.paged_prefill_chunk_fn = None
 
     def _resident_prefill(self, weights, prompt):
-        """Driver twin of the jitted whole-tree ``prefill``: full causal
+        """Per-layer form of the jitted whole-tree ``prefill``: full causal
         attention per layer, each layer's (k, v) written into the
         zero-padded cache row as it is produced."""
         B, S = prompt.shape
@@ -408,13 +377,14 @@ class ServeSteps:
                 lp = weights.get(l)
                 weights.prefetch((l + 1) % weights.n_layers)
                 x, (k, v) = self._pblock_fn(lp, x, positions)
-                cache = self._write_fn(cache, k, v, jnp.int32(l))
+                cache = self._write_fn(cache, k, v, jnp.int32(l),
+                                       jnp.int32(0))
                 _fence(x)
         return self._head_last_fn(weights.globals, x), cache
 
-    def _resident_step(self, weights, tokens, cache, pos):
-        """Driver twin of ``decode_step`` AND ``prefill_chunk`` (the cached
-        block reads S from the token shape, exactly like the scan bodies).
+    def _resident_step(self, weights, tokens, cache, pos, table=None):
+        """Per-layer form of ``decode_step`` AND ``prefill_chunk`` (the cached
+        layer body reads S from the token shape).
 
         The overlap: ``get(l)`` returns layer l's slot (usually already
         decoded by the worker), ``prefetch(l+1)`` kicks off the next
@@ -424,12 +394,14 @@ class ServeSteps:
         the next step.
         """
         x = self._embed_fn(weights.globals, tokens)
+        positions = self.mod.step_positions(pos, tokens.shape[1])
         weights.prefetch(0)
         for l in range(weights.n_layers):
             with obs_trace.span("serve.layer", layer=l, phase="step"):
                 lp = weights.get(l)
                 weights.prefetch((l + 1) % weights.n_layers)
-                x, cache = self._rblock_fn(lp, x, cache, jnp.int32(l), pos)
+                x, cache = self._rblock_fn(lp, x, cache, jnp.int32(l), pos,
+                                           positions, table)
                 _fence(x)
         return self._head_fn(weights.globals, x), cache
 

@@ -3,9 +3,9 @@
 The subsystem splits along the device/host line:
 
 * :mod:`repro.models` owns the device side — ``init_kv_pool`` block pools
-  and the ``paged_prefill_chunk`` / ``paged_decode_step`` twins that
-  scatter/gather K/V through a block table (``api.supports_paged_kv``
-  gates families);
+  (``models/kv.py``) and the one cached step, ``decode_step`` /
+  ``prefill_chunk``, which given a block table scatters and gathers K/V
+  through it (``api.has_cached_step`` gates families);
 * :class:`BlockKVManager` (here) owns the host side — block tables, free
   lists, prefix-chain refcounts, LRU eviction;
 * :class:`ColdBlockStore` entropy-codes evicted shared blocks to host

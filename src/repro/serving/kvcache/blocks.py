@@ -6,8 +6,8 @@ instead of one contiguous ``max_len`` row per slot it owns a pool of
 fixed-size blocks — ``init_kv_pool(cfg, n_blocks, block_size, bits)`` — and
 a host-side ``(n_slots, max_blocks)`` int32 block table routing every slot's
 logical positions to pool blocks.  The jitted step functions
-(``paged_prefill_chunk`` / ``paged_decode_step``) scatter and gather through
-that table; everything else — free lists, refcounts, the prefix-chain map,
+(``prefill_chunk`` / ``decode_step``, given the table) scatter and gather
+through it; everything else — free lists, refcounts, the prefix-chain map,
 LRU cold eviction — is plain host bookkeeping here.
 
 Layout invariants the step functions rely on:

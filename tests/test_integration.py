@@ -259,7 +259,7 @@ def test_ef_gradient_compression_unbiased():
 
 def test_int8_kv_cache_matches_bf16():
     """H3 optimization: int8 KV cache decode matches bf16-cache decode."""
-    from repro.models import dense
+    from repro.models import kv
     cfg = registry.reduced(registry.get("qwen3-1.7b"))
     mod = api.build(cfg)
     params = mod.init(cfg, jax.random.PRNGKey(0))
@@ -267,8 +267,8 @@ def test_int8_kv_cache_matches_bf16():
     toks = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab)
     logits, c16 = jax.jit(lambda p, t: mod.prefill(cfg, p, t, max_len=S + 4))(
         params, toks)
-    kq, ks = dense.quantize_kv(c16["k"])
-    vq, vs = dense.quantize_kv(c16["v"])
+    kq, ks = kv.quantize_kv(c16["k"])
+    vq, vs = kv.quantize_kv(c16["v"])
     c8 = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
     tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
     step = jax.jit(lambda p, t, c: mod.decode_step(cfg, p, t, c, S))

@@ -76,10 +76,11 @@ def test_kv_spec_parse_roundtrip():
 
 
 def test_supports_paged_kv_gates_families():
-    assert api.supports_paged_kv(_cfg())
-    assert api.supports_paged_kv(
+    # the paged pool rides the cached step, so having it is the gate
+    assert api.has_cached_step(_cfg())
+    assert api.has_cached_step(
         registry.reduced(registry.get("qwen2-moe-a2.7b")))
-    assert not api.supports_paged_kv(
+    assert not api.has_cached_step(
         registry.reduced(registry.get("mamba2-370m")))
 
 

@@ -1,4 +1,6 @@
 """Numerical correctness of the model-math building blocks."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,8 +9,8 @@ import pytest
 from repro.configs import registry
 from repro.distributed import ctx
 from repro.launch import mesh as mesh_lib
-from repro.models import dense
-from repro.models.dense import dequantize_kv, quantize_kv
+from repro.models import api, dense
+from repro.models.kv import dequantize_kv, quantize_kv
 from repro.models.layers import gqa_attention, rms_norm, rope, softmax_xent
 from repro.models.mamba2 import _causal_conv, ssd_chunked, ssd_step
 from repro.obs import metrics as obs_metrics
@@ -250,53 +252,93 @@ def test_moe_dispatch_matches_dense_ffn_when_experts_identical():
     assert np.isfinite(float(aux))
 
 
-def _layer_by_layer(cfg, params, tokens, cache, pos):
-    """The same step driven one layer at a time through `resident_block`."""
-    x = jax.jit(lambda p, t: dense.embed_step(cfg, p, t))(params, tokens)
-    block = jax.jit(lambda lp, x, c, l, s: dense.resident_block(cfg, lp, x, c,
-                                                                l, s))
+# cached-step families, each at its registry's reduced size
+FAMILIES = {"dense": "qwen3-1.7b", "moe": "qwen2-moe-a2.7b"}
+# (cache layout, position form): the paged pool serves continuous batching
+# only, so it takes per-slot positions
+LAYOUTS = [("slot16", "scalar"), ("slot16", "slots"), ("slot8", "scalar"),
+           ("slot8", "slots"), ("paged16", "slots")]
+
+
+def _fill(cache, seed):
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(cache))
+    return {name: jax.random.randint(r, a.shape, -127, 128, jnp.int8)
+            if a.dtype == jnp.int8
+            else jax.random.normal(r, a.shape).astype(a.dtype)
+            for r, (name, a) in zip(keys, cache.items())}
+
+
+def _layer_by_layer(cfg, params, tokens, cache, pos, *table):
+    """The same step driven one layer at a time through the per-layer
+    pieces compressed-resident serving jits (`ServeSteps`)."""
+    mod = api.build(cfg)
+    x = jax.jit(functools.partial(mod.embed, cfg))(params, tokens)
+    positions = mod.step_positions(pos, tokens.shape[1])
+    layer = jax.jit(functools.partial(mod.cached_layer, cfg))
     stack = dense._layer_stack(params)
     for l in range(cfg.n_layers):
         lp = {k: v[l] for k, v in stack.items()}
-        x, cache = block(lp, x, cache, jnp.int32(l), pos)
-    return jax.jit(lambda p, x: dense.head_step(cfg, p, x))(params, x), cache
+        x, cache = layer(lp, x, cache, jnp.int32(l), pos, positions, *table)
+    return jax.jit(functools.partial(mod.head, cfg))(params, x), cache
 
 
-@pytest.mark.parametrize("kv_bits", [16, 8])
-@pytest.mark.parametrize("pos_form", ["scalar", "slots"])
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+@pytest.mark.parametrize("layout,pos_form", LAYOUTS)
 @pytest.mark.parametrize("S", [1, 4])
 def test_cached_step_writes_only_its_rows_and_matches_layer_by_layer(
-        S, pos_form, kv_bits):
+        S, layout, pos_form, family):
     """`decode_step` (S=1) and `prefill_chunk` (S=4) carry the layer-stacked
     cache through their scan: the returned cache differs from the one passed
     in only at rows [pos_b, pos_b + S) of each layer and slot, and logits and
-    cache are bitwise those of the step driven layer by layer."""
-    cfg = registry.reduced(registry.get("qwen3-1.7b"))
-    params = dense.init(cfg, jax.random.PRNGKey(0))
-    B, T = 3, 24
-    cache = dense.init_cache(cfg, B, T, kv_bits=kv_bits)
-    keys = jax.random.split(jax.random.PRNGKey(1), len(cache))
-    cache = {name: jax.random.randint(r, a.shape, -127, 128, jnp.int8)
-             if a.dtype == jnp.int8
-             else jax.random.normal(r, a.shape).astype(a.dtype)
-             for r, (name, a) in zip(keys, cache.items())}
+    cache are bitwise those of the step driven layer by layer.  The paged
+    pool holds the slot stack's rows in shuffled blocks: the step changes
+    only the blocks its table names for those rows, and its logits are
+    bitwise the slot stack's."""
+    cfg = registry.reduced(registry.get(FAMILIES[family]))
+    mod = api.build(cfg)
+    params = mod.init(cfg, jax.random.PRNGKey(0))
+    B, T, BS = 3, 24, 4
+    cache = _fill(mod.init_cache(cfg, B, T,
+                                 kv_bits=8 if layout == "slot8" else 16), 1)
     pos = jnp.int32(5) if pos_form == "scalar" \
         else jnp.array([0, 9, T - S], jnp.int32)
     tokens = jnp.arange(1, B * S + 1, dtype=jnp.int32).reshape(B, S)
-    step = dense.decode_step if S == 1 else dense.prefill_chunk
-    logits, out = jax.jit(lambda p, t, c, s: step(cfg, p, t, c, s))(
-        params, tokens, cache, pos)
-    want_logits, want = _layer_by_layer(cfg, params, tokens, cache, pos)
-
-    assert logits.shape == (B, S, cfg.padded_vocab())
-    np.testing.assert_array_equal(np.asarray(logits), np.asarray(want_logits))
+    step = mod.decode_step if S == 1 else mod.prefill_chunk
+    run = jax.jit(lambda p, t, c, s, *bt: step(cfg, p, t, c, s, *bt))
+    logits, out = run(params, tokens, cache, pos)
     starts = np.broadcast_to(np.asarray(pos), (B,))
-    written = np.zeros((B, T), bool)
+    written = np.zeros((B, T), bool)                   # (B, T) rows
     for b, p in enumerate(starts):
         written[b, p:p + S] = True
-    for k in cache:
-        got, before = np.asarray(out[k]), np.asarray(cache[k])
+    before, table = cache, ()
+
+    if layout == "paged16":
+        slot_logits, slot_out = logits, out
+        MB = T // BS
+        bt = 1 + np.random.default_rng(2).permutation(B * MB).reshape(B, MB)
+        pool = _fill(mod.init_kv_pool(cfg, 1 + B * MB, BS), 3)
+        pool = {n: a.at[:, bt].set(cache[n].reshape(
+                    cache[n].shape[:2] + (MB, BS) + cache[n].shape[3:]))
+                for n, a in pool.items()}
+        before, table = pool, (jnp.asarray(bt, jnp.int32),)
+        logits, out = run(params, tokens, pool, pos, *table)
+        np.testing.assert_array_equal(np.asarray(logits),
+                                      np.asarray(slot_logits))
+        for k in pool:       # read back through the table: the slot rows
+            np.testing.assert_array_equal(
+                np.asarray(out[k])[:, bt].reshape(slot_out[k].shape),
+                np.asarray(slot_out[k]), err_msg=k)
+        rows, written = written, np.zeros(pool["k"].shape[1:3], bool)
+        for b, t in zip(*np.nonzero(rows)):          # -> (NB, BS) slots
+            written[bt[b, t // BS], t % BS] = True
+
+    want_logits, want = _layer_by_layer(cfg, params, tokens, before, pos,
+                                        *table)
+    assert logits.shape == (B, S, cfg.padded_vocab())
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(want_logits))
+    for k in before:
+        got, prev = np.asarray(out[k]), np.asarray(before[k])
         np.testing.assert_array_equal(got, np.asarray(want[k]), err_msg=k)
-        changed = (got != before).any(axis=(3, 4))          # (L, B, T)
+        changed = (got != prev).any(axis=(3, 4))     # (L, B, T) / (L, NB, BS)
         assert not (changed & ~written).any(), k
         assert changed[:, written].any(), k

@@ -204,7 +204,7 @@ def test_resident_mode_guardrails():
     with pytest.raises(ValueError, match="resident"):
         serving_engine.ServeSteps(_cfg("dense"), sc, resident="bogus")
     ssm = registry.reduced(registry.get("mamba2-370m"))
-    assert not api.supports_resident_serving(ssm)
+    assert not api.has_cached_step(ssm)
     with pytest.raises(NotImplementedError, match="per-layer"):
         serving_engine.ServeSteps(ssm, sc, resident="compressed")
 

@@ -1,24 +1,18 @@
 """Tests for the repro-lint static-analysis pass (src/repro/analysis/).
 
-Each checker gets a positive fixture (a violation it must flag) and a
-negative fixture (compliant code it must stay silent on); the twin checker
-additionally gets a *real* perturbation test — a resident twin with one
-extra bf16 multiply must produce a divergence finding, which is the
-acceptance mechanism for the whole pass (a checker that cannot fail proves
-nothing).  The baseline file round-trips and the split logic implements
-the empty-delta gate.
+Each checker gets a positive fixture (a violation it must flag — a
+checker that cannot fail proves nothing) and a negative fixture
+(compliant code it must stay silent on).  The baseline file round-trips
+and the split logic implements the empty-delta gate.
 """
 import ast
 import textwrap
-
-import pytest
 
 from repro.analysis import base
 from repro.analysis import catalog as cat
 from repro.analysis import dtypes
 from repro.analysis import jit_boundary as jb
 from repro.analysis import locks
-from repro.analysis import twins
 
 
 # ------------------------------------------------------------ base/baseline
@@ -250,65 +244,3 @@ def test_catalog_collect_emits_and_dynamic_name(tmp_path):
 
 def test_catalog_sync_clean_on_repo():
     assert cat.check(base.REPO_ROOT) == []
-
-
-# ---------------------------------------------------------------- twins
-
-@pytest.fixture(scope="module")
-def dense_setup():
-    import jax
-    import jax.numpy as jnp
-    from repro.models import dense
-    cfg = twins._tiny_cfg("dense")
-    params = dense.init(cfg, jax.random.PRNGKey(0))
-    lp0 = {k: v[0] for k, v in dense._layer_stack(params).items()}
-    cache = dense.init_cache(cfg, 2, 8)
-    posv = jnp.zeros((2,), jnp.int32)
-    token1 = jnp.zeros((2, 1), jnp.int32)
-    x1 = jnp.zeros((2, 1, cfg.d_model), params["embed"].dtype)
-    return dense, cfg, params, lp0, cache, posv, token1, x1
-
-
-def test_twin_pair_clean(dense_setup):
-    import jax
-    dense, cfg, params, lp0, cache, posv, token1, x1 = dense_setup
-    ref = twins.canonical_ops(twins.scan_body(jax.make_jaxpr(
-        lambda: dense.decode_step(cfg, params, token1, cache, posv))()))
-    twin = twins.canonical_ops(jax.make_jaxpr(
-        lambda: dense.resident_block(cfg, lp0, x1, cache, 0, posv))())
-    assert ref, "canonicalization must keep float ops"
-    assert twins.diff_ops(ref, twin) == ""
-
-
-def test_twin_perturbation_detected(dense_setup):
-    """The acceptance mechanism: a deliberately perturbed twin (one extra
-    bf16 multiply on the block output) must yield a divergence finding."""
-    import jax
-    import jax.numpy as jnp
-    dense, cfg, params, lp0, cache, posv, token1, x1 = dense_setup
-    ref = twins.canonical_ops(twins.scan_body(jax.make_jaxpr(
-        lambda: dense.decode_step(cfg, params, token1, cache, posv))()))
-
-    def perturbed():
-        y, c = dense.resident_block(cfg, lp0, x1, cache, 0, posv)
-        return y * y.dtype.type(1.0001), c
-
-    twin = twins.canonical_ops(jax.make_jaxpr(perturbed)())
-    msg = twins.diff_ops(ref, twin)
-    assert msg != ""
-    assert "mul" in msg
-
-
-def test_twin_dropped_op_detected(dense_setup):
-    # a twin that *loses* an op diverges too (symmetry of the contract)
-    import jax
-    dense, cfg, params, lp0, cache, posv, token1, x1 = dense_setup
-    ref = twins.canonical_ops(twins.scan_body(jax.make_jaxpr(
-        lambda: dense.decode_step(cfg, params, token1, cache, posv))()))
-    assert "additionally computes" in twins.diff_ops(ref, ref[:-1])
-
-
-def test_scan_body_raises_without_scan():
-    import jax
-    with pytest.raises(ValueError, match="no scan"):
-        twins.scan_body(jax.make_jaxpr(lambda x: x + 1.0)(1.0))

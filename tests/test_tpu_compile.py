@@ -122,10 +122,13 @@ def test_qwen3_cached_decode_block_compiles(one_chip):
                          jax.eval_shape(lambda: dense.init_cache(cfg, B, T)))
     assert lp["wq"].q.shape == (2048, 2048) and lp["w_up"].q.shape == (
         2048, 6144)
-    c = jax.jit(functools.partial(dense.resident_block, cfg)).lower(
+    # jitted as ServeSteps jits it for compressed residency: the stacked
+    # cache donated
+    c = jax.jit(functools.partial(dense.cached_layer, cfg),
+                donate_argnums=(2,)).lower(
         lp, _sds(one_chip, (B, 1, cfg.d_model), jnp.bfloat16), cache,
-        _sds(one_chip, (), jnp.int32), _sds(one_chip, (B,), jnp.int32)
-    ).compile()
+        _sds(one_chip, (), jnp.int32), _sds(one_chip, (B,), jnp.int32),
+        _sds(one_chip, (B, 1), jnp.int32)).compile()
     mem = c.memory_analysis()
     assert mem.output_size_in_bytes > 0
     # attention reads K/V as stored: no temporary as large as one layer's
