@@ -165,6 +165,23 @@ def deq(w: Any, dtype=jnp.bfloat16) -> jax.Array:
     return w.astype(dtype) if w.dtype != dtype else w
 
 
+def round_bf16(w: jax.Array) -> jax.Array:
+    """``w`` rounded to bf16's grid (round-to-nearest-even, exactly as
+    ``w.astype(bf16)``) and kept float32."""
+    return jax.lax.reduce_precision(w, exponent_bits=8, mantissa_bits=7)
+
+
+def _rounds_weight(x: jax.Array, w: Any) -> bool:
+    """Whether ``matmul`` rounds ``w`` to bf16 itself when lowered for the
+    TPU: a plain float32 weight against float32 or bf16 activations under
+    the default matmul precision, where the TPU dot takes both operands in
+    bf16 anyway."""
+    return (not isinstance(w, (QT, QT4, QTG, FusedQT))
+            and w.dtype == jnp.float32
+            and x.dtype in (jnp.float32, jnp.bfloat16)
+            and jax.config.jax_default_matmul_precision is None)
+
+
 def matmul(x: jax.Array, w: Any, dim_nums: Optional[str] = None) -> jax.Array:
     """x @ w with on-the-fly dequantization (fused by XLA into the dot).
 
@@ -176,14 +193,32 @@ def matmul(x: jax.Array, w: Any, dim_nums: Optional[str] = None) -> jax.Array:
     the fused entropy-decode→dequant→matmul kernel — the weight never
     exists densely; the handle's jit path runs the exact ``deq`` ops after
     an in-graph decode, so it stays bit-identical to a QT slot.
+
+    A float32 weight is rounded to bf16 here, inside the dot's fusion, when
+    the program is lowered for the TPU, whose default-precision dot takes
+    it in bf16 anyway: left to the dot (or to ``deq``'s cast for bf16
+    activations), the rounding becomes a bf16 copy of the whole
+    layer-stacked weight, written before the layer scan on every call.
+    The dot accumulates in f32 and its output keeps ``x``'s dtype, as
+    before.  Every other platform keeps the plain dot, which does not
+    round.  The counter ``matmul.weight`` (label
+    ``operand=rounded_f32|as_given``) counts each trace's choice.
     """
+    def dot(x, wd):
+        return x @ wd if dim_nums is None else jnp.einsum(dim_nums, x, wd)
+
+    rounds = _rounds_weight(x, w)
+    obs_metrics.counter("matmul.weight").inc(
+        operand="rounded_f32" if rounds else "as_given")
     if isinstance(w, FusedQT):
         assert dim_nums is None, "FusedQT weights support plain x @ w only"
         return fused_decode_matmul(x, w)
-    wd = deq(w, x.dtype)
-    if dim_nums is None:
-        return x @ wd
-    return jnp.einsum(dim_nums, x, wd)
+    if not rounds:
+        return dot(x, deq(w, x.dtype))
+    return jax.lax.platform_dependent(
+        x, gather_weight(w),
+        tpu=lambda x, w: dot(x, round_bf16(w)).astype(x.dtype),
+        default=lambda x, w: dot(x, w.astype(x.dtype)))
 
 
 def take_rows(w: Any, idx: jax.Array) -> jax.Array:

@@ -190,6 +190,7 @@ INFORMATIONAL_POINTS: Dict[str, List[str]] = {
         "kv.dropped_evictions",
         "kv.shared_misses",         # zero on non-sharing traffic
         "load.decodes",
+        "matmul.weight",            # trace-time: one per traced matmul
         "queue.shed",               # only under overload
         "requests.finished",
         "resident.fused_fallback",  # zero when every tensor fuses

@@ -9,7 +9,7 @@ import pytest
 from repro.configs import registry
 from repro.distributed import ctx
 from repro.launch import mesh as mesh_lib
-from repro.models import api, dense
+from repro.models import api, dense, layers
 from repro.models.kv import dequantize_kv, quantize_kv
 from repro.models.layers import gqa_attention, rms_norm, rope, softmax_xent
 from repro.models.mamba2 import _causal_conv, ssd_chunked, ssd_step
@@ -142,6 +142,105 @@ def test_attn_form_follows_sharding_hints(KV, form):
     assert _form_count(other) == counts[other]
     want = _naive_attention(q, k, v, causal=False, kv_len=11)
     np.testing.assert_allclose(np.asarray(out, np.float32), want, atol=5e-2)
+
+
+# weight kind -> (activation dtype, weight dtype or packing, matmul.weight)
+WEIGHTS = {
+    "f32": (jnp.float32, jnp.float32, "rounded_f32"),
+    "f32-bf16-act": (jnp.bfloat16, jnp.float32, "rounded_f32"),
+    "bf16": (jnp.bfloat16, jnp.bfloat16, "as_given"),
+    "bf16-f32-act": (jnp.float32, jnp.bfloat16, "as_given"),
+    "qt": (jnp.bfloat16, "qt", "as_given"),
+    "fused": (jnp.bfloat16, "fused", "as_given"),
+}
+
+
+def _weight_case(kind):
+    """(x, w as matmul takes it, the dense weight today's dot reads)."""
+    xdt, wdt, _ = WEIGHTS[kind]
+    if wdt in ("qt", "fused"):
+        from differential import qt_cases
+        from repro.kernels.fused_decode_matmul import build_fused_qt
+        c = qt_cases.fused_case(bits=8, codec="huffman", K=8, N=16, seg=32)
+        qt = layers.pack_qt(c.sym, c.scale, c.zero, bits=8)
+        qt = type(qt)(*(jnp.asarray(p) for p in qt))
+        w = qt if wdt == "qt" else build_fused_qt(
+            c.table, c.mat, c.scale, c.zero, seg_symbols=c.seg, K=c.K,
+            N=c.N, bits=c.bits, impl="jax")
+        return c.x.astype(xdt), w, qt
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.normal(size=(3, 64)), xdt)
+    w = jnp.asarray(rng.normal(0.0, 0.05, size=(64, 48)), wdt)
+    return x, w, w
+
+
+def _weight_count(operand):
+    return obs_metrics.counter("matmul.weight").value(operand=operand)
+
+
+@pytest.mark.parametrize("kind", sorted(WEIGHTS))
+def test_matmul_keeps_todays_dot_off_the_tpu(kind):
+    """On the CPU ``matmul`` is today's ``x @ deq(w, x.dtype)`` to the bit,
+    for every weight kind, and ``matmul.weight`` counts each trace by
+    whether the TPU program rounds the weight itself."""
+    x, w, dense_w = _weight_case(kind)
+    label = WEIGHTS[kind][2]
+    want = jax.jit(lambda x, w: x @ layers.deq(w, x.dtype))(x, dense_w)
+    before = {op: _weight_count(op) for op in ("rounded_f32", "as_given")}
+    got = jax.jit(lambda x, w: layers.matmul(x, w))(x, w)
+    assert got.dtype == want.dtype == x.dtype
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    np.testing.assert_array_equal(np.asarray(layers.matmul(x, w), np.float32),
+                                  np.asarray(want, np.float32))
+    assert _weight_count(label) == before[label] + 2     # traced twice
+    other = "as_given" if label == "rounded_f32" else "rounded_f32"
+    assert _weight_count(other) == before[other]
+
+
+def test_round_bf16_is_the_bf16_cast():
+    """The TPU branch's weight is the float32 weight on bf16's grid, exactly
+    as a cast to bf16 rounds it: nearest, ties to even, subnormals and
+    infinities included."""
+    rng = np.random.default_rng(11)
+    one = np.float32(1.0)
+    edge = np.array([0.0, -0.0, one + 2.0 ** -8, one + 3 * 2.0 ** -8,
+                     -(one + 2.0 ** -8), 1e-40, -3e-39, 3.4e38,
+                     np.finfo(np.float32).max, np.inf, -np.inf], np.float32)
+    w = np.concatenate([rng.normal(0.0, 0.05, 4096).astype(np.float32),
+                        rng.normal(0.0, 1e3, 1024).astype(np.float32),
+                        rng.integers(0, 2 ** 32, 1024, dtype=np.uint32
+                                     ).view(np.float32), edge])
+    w = w[np.isfinite(w) | np.isinf(w)]
+    want = np.asarray(jnp.asarray(w).astype(jnp.bfloat16).astype(jnp.float32))
+    for got in (layers.round_bf16(jnp.asarray(w)),
+                jax.jit(layers.round_bf16)(jnp.asarray(w))):
+        assert got.dtype == jnp.float32
+        np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                      want.view(np.uint32))
+
+
+@pytest.mark.parametrize("kind", ["f32", "f32-bf16-act", "bf16", "qt"])
+def test_matmul_rounds_float32_weights_only_for_the_tpu(kind):
+    """Lowered for the TPU, a float32 weight reaches its dot through
+    ``reduce_precision`` and is never cast to bf16; lowered for the CPU, or
+    under a non-default matmul precision, the program is today's."""
+    x, w, _ = _weight_case(kind)
+    rounds = WEIGHTS[kind][2] == "rounded_f32"
+    f = jax.jit(lambda x, w: layers.matmul(x, w))
+
+    def text(platform):
+        return f.trace(x, w).lower(lowering_platforms=(platform,)).as_text()
+
+    w_bf16 = "tensor<%sxbf16>" % "x".join(map(str, (64, 48)))
+    tpu, cpu = text("tpu"), text("cpu")
+    assert ("reduce_precision" in tpu) == rounds
+    assert "reduce_precision" not in cpu
+    if rounds:
+        assert w_bf16 not in tpu
+        assert (w_bf16 in cpu) == (x.dtype == jnp.bfloat16)
+    with jax.default_matmul_precision("highest"):
+        assert "reduce_precision" not in text("tpu")
 
 
 def test_rope_preserves_norm_and_relativity():

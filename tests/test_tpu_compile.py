@@ -170,6 +170,43 @@ def test_qwen3_decode_step_updates_donated_cache_in_place(one_chip):
     assert not bulk, bulk
 
 
+@pytest.mark.parametrize("program,B,S", [("decode_step", 32, 1),
+                                         ("prefill_chunk", 1, 256)])
+def test_qwen3_step_reads_float32_weights_in_the_dot(one_chip, program, B, S):
+    """The ``qwen3-1.7b.chat`` cell's two programs (the decode step over 32
+    slots and the one-slot 256-token prefill chunk, float32 weights, 1536
+    cache rows): each layer's dot rounds its float32 weight to bf16 inside
+    its own fusion, so no bf16 copy of a layer-stacked weight is written
+    before the scan, and no temporary is as large as one layer's bf16 MLP
+    weights."""
+    from repro.configs import registry
+    from repro.models import dense
+    cfg = registry.published(registry.get("qwen3-1.7b"), 4)
+    T = 1536
+
+    def f32(s):
+        floating = jnp.issubdtype(s.dtype, jnp.floating)
+        return _sds(one_chip, s.shape, jnp.float32 if floating else s.dtype)
+
+    params = jax.tree.map(f32, jax.eval_shape(
+        functools.partial(dense.init, cfg), jax.random.PRNGKey(0)))
+    cache = jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype),
+                         jax.eval_shape(lambda: dense.init_cache(cfg, B, T)))
+    c = jax.jit(functools.partial(getattr(dense, program), cfg),
+                donate_argnums=(2,)).lower(
+        params, _sds(one_chip, (B, S), jnp.int32), cache,
+        _sds(one_chip, (B,), jnp.int32)).compile()
+    stacks = {v.shape for k, v in params.items()
+              if k.startswith("layers/") and v.ndim == 3}
+    assert (4, cfg.d_model, cfg.d_ff) in stacks
+    copies = [m for shape in stacks for m in re.findall(
+        r"%\S+ = " + re.escape("bf16[%s]" % ",".join(map(str, shape)))
+        + r"\{", c.as_text())]
+    assert not copies, copies
+    mlp_bf16 = 3 * cfg.d_model * cfg.d_ff * 2
+    assert c.memory_analysis().temp_size_in_bytes < mlp_bf16
+
+
 # Mosaic's gather lowering refuses the per-lane LUT reads: an AssertionError
 # for the prefix kernels, "Only 2D gather is supported" for the tANS ones.
 _REFUSED = pytest.mark.xfail(strict=True,
