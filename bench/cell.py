@@ -4,6 +4,9 @@ A cell (an entry of ``workloads``) names a configuration and a traffic mix;
 each lives in a file of its own, and so does every metric's reader:
 
 - ``configs[*].file``              the configuration (``bench/configs/``)
+- the ``.py`` beside that file      where the block needs it, the family's
+                                   sizes, program settings and work counts
+                                   (``bench/modelcfg.py``)
 - ``bench/traffic/<traffic>.json`` the traffic mix and the server's slots,
                                    chunk and cache length
 - ``bench/metrics/<metric>.py``    ``read(run) -> float | None``
@@ -11,8 +14,12 @@ each lives in a file of its own, and so does every metric's reader:
                                    compares, with the readings it was set from
 - ``bench/peaks.json``             the chip's peaks, by ``device_kind``
 
-A new cell, mix or metric is a new file and a new entry; no file that
-exists needs an edit.
+A new cell, mix, metric or configuration is a new file and a new entry;
+no file that exists needs an edit.  That holds for a block ``Dims`` cannot
+state too (sparse experts, say): its adapter brings the family's sizes into
+``Dims.family_sizes``, the program's ``ArchConfig`` settings and its own
+count of operations and bytes.  A family with no reference module yet
+(``bench/reference/<family>.py``) brings that as a new file as well.
 """
 from __future__ import annotations
 
@@ -20,6 +27,7 @@ import dataclasses
 import importlib.util
 import json
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 import modelcfg
@@ -40,6 +48,7 @@ class Cell:
     per_layer: List[dict]
     limits: Dict[str, dict]     # number compared -> {"limit": ..., ...}
     root: Path
+    adapter: Optional[ModuleType] = None    # the configuration's ``.py``
 
 
 def _reported(metric: dict, workload: str) -> bool:
@@ -54,7 +63,7 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
                        f"known: {sorted(cells)}")
     w = cells[workload]
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
-    raw, dims = modelcfg.load(root / conf["file"])
+    raw, dims, adapter = modelcfg.load(root / conf["file"])
     mix = json.loads((root / "bench" / "traffic" / f"{w['traffic']}.json")
                      .read_text())
     lim_file = root / "bench" / "limits" / f"{workload}.json"
@@ -65,7 +74,7 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
                             if _reported(m, workload)],
                 per_layer=[m for m in bench["per_layer"]
                            if _reported(m, workload)],
-                limits=limits, root=root)
+                limits=limits, root=root, adapter=adapter)
 
 
 def reader(metric: str, root: Path = ROOT) -> Callable[[object],

@@ -4,8 +4,25 @@ the harness and the reference use.
 Configuration files keep the key names of the model's own published
 ``config.json``; those differ between model families (``num_hidden_layers``
 against ``num_layers``, ``rope_theta`` against ``rope_ratio``), so each size
-is looked up under every name it is published as.  A file whose keys none of
-these cover brings a ``bench/configs/<name>.py`` with ``dims(raw)`` beside it.
+is looked up under every name it is published as.
+
+A configuration whose block ``Dims``' shared sizes cannot state brings an
+adapter, ``bench/configs/<name>.py`` beside its file, as a new file.  Each
+of its functions is optional and is found by name:
+
+- ``dims(raw) -> Dims``: the served sizes, in place of ``dims`` here; the
+  sizes the family adds (experts, top-k, expert widths, ...) go into
+  ``Dims.family_sizes`` (``family_sizes(dict)``);
+- ``arch_kwargs(raw) -> dict``: further keyword arguments of the program's
+  ``ArchConfig``, as plain values; a dict for a field whose type is a
+  dataclass (``moe``: ``MoEConfig``) is built into it
+  (``bench/serve_cell.arch_config``);
+- ``matmul_flops(d)``, ``decode_weight_bytes(d, stored, active)``: the
+  family's own work counts (``bench/work.py``, whose sparse-expert counts
+  an adapter can name).
+
+A file with no adapter gives the ``Dims`` of ``dims`` here, with no family
+sizes, the ``ArchConfig`` of those sizes and the dense counts.
 """
 from __future__ import annotations
 
@@ -13,6 +30,8 @@ import dataclasses
 import importlib.util
 import json
 from pathlib import Path
+from types import ModuleType
+from typing import Optional, Tuple
 
 # size -> the published key names it may come under, first found wins
 ALIASES = {
@@ -41,6 +60,22 @@ class Dims:
     eps: float
     tie: bool
     qk_norm: bool
+    # the sizes a family adds, as sorted (name, value) pairs: hashable, so
+    # Dims stays a static argument of the references' jitted functions
+    family_sizes: Tuple[Tuple[str, object], ...] = ()
+
+    def size(self, name: str):
+        """One of ``family_sizes``; a missing one is an error."""
+        sizes = dict(self.family_sizes)
+        if name not in sizes:
+            raise KeyError(f"{self.family}: no family size {name!r} "
+                           f"(has {sorted(sizes)})")
+        return sizes[name]
+
+
+def family_sizes(sizes: dict) -> Tuple[Tuple[str, object], ...]:
+    """``sizes`` in the form ``Dims.family_sizes`` keeps them."""
+    return tuple(sorted(sizes.items()))
 
 
 def _pick(raw: dict, size: str):
@@ -71,16 +106,21 @@ def dims(raw: dict) -> Dims:
                 qk_norm=bool(raw.get("qk_norm", False)))
 
 
+def adapter(path: Path) -> Optional[ModuleType]:
+    """The ``.py`` beside a configuration file, imported, or None."""
+    file = Path(path).with_suffix(".py")
+    if not file.exists():
+        return None
+    spec = importlib.util.spec_from_file_location(
+        f"bench_config_{file.stem.replace('-', '_').replace('.', '_')}", file)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load(path: Path):
-    """``(raw file contents, Dims)`` for a configuration file; a ``.py``
-    beside the ``.json`` with a ``dims(raw)`` function takes precedence."""
+    """``(raw file contents, Dims, adapter or None)`` for a configuration
+    file; the adapter's ``dims(raw)``, where it has one, takes precedence."""
     raw = json.loads(Path(path).read_text())
-    adapter = Path(path).with_suffix(".py")
-    if adapter.exists():
-        spec = importlib.util.spec_from_file_location(
-            f"bench_config_{adapter.stem.replace('-', '_').replace('.', '_')}",
-            adapter)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return raw, mod.dims(raw)
-    return raw, dims(raw)
+    mod = adapter(path)
+    return raw, getattr(mod, "dims", dims)(raw), mod

@@ -22,7 +22,7 @@ import dataclasses
 import gc
 import sys
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, get_args, get_type_hints
 
 import numpy as np
 
@@ -72,15 +72,46 @@ def _annotate(name: str, on: bool):
 
 
 def arch_config(cell: Cell):
-    """The program's ``ArchConfig`` for the cell's configuration."""
+    """The program's ``ArchConfig`` for the cell's configuration: the sizes
+    ``Dims`` states, and the further keyword arguments its adapter declares
+    (``arch_kwargs(raw)``, ``bench/modelcfg.py``)."""
     from repro.configs.base import ArchConfig
     d = cell.dims
-    return ArchConfig(
+    kw = dict(
         name=cell.config_name, family=d.family, n_layers=d.layers,
         d_model=d.d_model, n_heads=d.heads, n_kv_heads=d.kv_heads,
         d_ff=d.d_ff, vocab=d.vocab, head_dim=d.head_dim, qk_norm=d.qk_norm,
         rope_theta=d.rope_theta, tie_embeddings=d.tie,
         source=cell.raw.get("source", ""))
+    declared = getattr(cell.adapter, "arch_kwargs", None)
+    if declared is not None:
+        kw.update(_declared_kwargs(ArchConfig, kw, declared(cell.raw)))
+    return ArchConfig(**kw)
+
+
+def _declared_kwargs(cls, given: dict, declared: dict) -> dict:
+    """``declared`` as keyword arguments of the dataclass ``cls``: a dict
+    given for a field whose type is a dataclass (``MoEConfig``,
+    ``SSMConfig``) is built into it.  A key ``cls`` does not take, or one
+    ``given`` already sets from ``Dims``, is an error."""
+    fields = {f.name for f in dataclasses.fields(cls)}
+    hints = get_type_hints(cls)
+    out = {}
+    for key, value in declared.items():
+        if key not in fields:
+            raise TypeError(f"arch_kwargs: {cls.__name__} takes no {key!r}")
+        if key in given:
+            raise TypeError(f"arch_kwargs: {key!r} comes from the "
+                            f"configuration's Dims, not from arch_kwargs")
+        if isinstance(value, dict):
+            kinds = [t for t in get_args(hints[key]) or (hints[key],)
+                     if dataclasses.is_dataclass(t)]
+            if not kinds:
+                raise TypeError(f"arch_kwargs: {cls.__name__}.{key} is no "
+                                f"dataclass; a dict cannot be built into it")
+            value = kinds[0](**value)
+        out[key] = value
+    return out
 
 
 def _to_program_layout(host: Dict[str, np.ndarray], shapes) -> None:
@@ -228,7 +259,7 @@ class ServedCell:
 
     def _step(self, phase: Optional[Phase]) -> None:
         from repro.serving.batching.request import RequestState
-        d = self.cell.dims
+        d, adapter = self.cell.dims, self.cell.adapter
         n0 = self.ce.n_decode_steps
         with _annotate("bench.ce_step", self.annotate):
             self.ce.step()
@@ -247,7 +278,8 @@ class ServedCell:
                     phase.tokens += new
                     if first:
                         phase.ttft.append(now - c.t_send)
-                        phase.flops += work.prefill_flops(d, req.prompt_len)
+                        phase.flops += work.prefill_flops(d, req.prompt_len,
+                                                          adapter)
                         phase.itl.extend([0.0] * (new - 1))
                     else:
                         phase.itl.append(now - c.t_last)
@@ -257,7 +289,7 @@ class ServedCell:
                     decoded += 1
                     kv_rows += rows
                     if phase is not None:
-                        phase.flops += work.decode_flops(d, rows)
+                        phase.flops += work.decode_flops(d, rows, adapter)
                 c.seen, c.t_last = n, now
                 if req.state is RequestState.FINISHED:
                     ended.append(c)
@@ -267,7 +299,8 @@ class ServedCell:
                     phase.decode_steps += 1
                     phase.decode_slots += decoded
                     phase.decode_bytes += (
-                        work.decode_weight_bytes(d, self.stored, decoded)
+                        work.decode_weight_bytes(d, self.stored, decoded,
+                                                 adapter)
                         + work.kv_bytes(d, kv_rows))
         for c in ended:
             if phase is not None:
